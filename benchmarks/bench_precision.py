@@ -35,7 +35,7 @@ from repro.core.analytic import (
     gaussian_marginal_moments, gaussian_score, gaussian_w2,
 )
 from repro.core.precision import PRESETS, resolve_policy
-from repro.models.dit import DiTConfig, init_dit, make_score_fn
+from repro.models.dit import DiTConfig, init_dit, liven_dit, make_score_fn
 
 from .common import emit, timed
 
@@ -76,7 +76,8 @@ def bench_dit(preset: str) -> dict:
                     num_heads=4, d_ff=128)
     sde = VPSDE()
     policy = resolve_policy(preset)
-    params = init_dit(net, jax.random.PRNGKey(0))
+    params = liven_dit(init_dit(net, jax.random.PRNGKey(0)),
+                       jax.random.PRNGKey(2))
     score = make_score_fn(params, net, sde, policy=policy)
     cfg = AdaptiveConfig(eps_rel=0.05, precision=preset)
     fn = jax.jit(lambda k: sample(sde, score, DIT_SHAPE, k,

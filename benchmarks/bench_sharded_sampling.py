@@ -1,4 +1,4 @@
-"""Sharded-sampling scaling: samples/sec for 1 vs N fake host devices.
+"""Sharded-sampling scaling: samples/sec for 1 vs N devices.
 
 Captures the data-parallel scaling axis of ``sample(..., mesh=...)``
 (DESIGN.md §3) in the ``name,us_per_call,derived`` CSV the perf
@@ -10,7 +10,11 @@ prior draw, constrained while-loop carry, shard_map'd fused kernel)
 relative to the single-device run, and it becomes a true scaling curve
 the moment it runs on real accelerators.
 
-Each device count runs in a subprocess (device count locks at jax init).
+On an accelerator every device count runs in this process, over a mesh
+of ``jax.devices()[:n]``: the process that holds the chips is the only
+one that can use them. On the CPU each count runs in a child process
+with that many fake devices (the count locks at jax init). A count that
+fails fails the suite.
 
   PYTHONPATH=src python -m benchmarks.bench_sharded_sampling [--devices 1,4]
 """
@@ -36,11 +40,12 @@ DIM = 256
 EPS_REL = 0.05
 
 
-def _child(n_devices: int, use_fused: bool) -> None:
+def _measure(n_devices: int, use_fused: bool) -> None:
     import jax
 
     from benchmarks.common import emit, timed
     from repro.core import AdaptiveConfig, VPSDE, sample
+    from repro.launch.mesh import make_data_mesh
 
     mu, s0 = 0.3, 0.5
     sde = VPSDE()
@@ -51,7 +56,10 @@ def _child(n_devices: int, use_fused: bool) -> None:
         std = std.reshape((-1, 1))
         return -(x - m * mu) / (m * m * s0 * s0 + std * std)
 
-    mesh = jax.make_mesh((n_devices,), ("data",)) if n_devices > 1 else None
+    if n_devices > jax.device_count():
+        raise ValueError(f"{n_devices} devices requested, "
+                         f"{jax.device_count()} present")
+    mesh = make_data_mesh(n_devices) if n_devices > 1 else None
     cfg = AdaptiveConfig(eps_rel=EPS_REL, use_fused_kernel=use_fused)
     fn = jax.jit(
         lambda k: sample(sde, score, (BATCH, DIM), k, config=cfg, mesh=mesh)
@@ -65,10 +73,18 @@ def _child(n_devices: int, use_fused: bool) -> None:
     )
 
 
-def main(device_counts=(1, 4)) -> None:
+def main(device_counts=None) -> None:
+    import jax
+
+    if jax.default_backend() != "cpu":
+        counts = device_counts or sorted({1, jax.device_count()})
+        for n in counts:
+            for fused in (False, True):
+                _measure(n, fused)
+        return
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
-    for n in device_counts:
+    for n in device_counts or (1, 4):
         for fused in (False, True):
             cmd = [sys.executable, "-m", "benchmarks.bench_sharded_sampling",
                    "--child", str(n)]
@@ -77,9 +93,9 @@ def main(device_counts=(1, 4)) -> None:
             r = subprocess.run(cmd, env=env, capture_output=True, text=True,
                                timeout=560, cwd=root)
             if r.returncode != 0:
-                print(f"# sharded_sampling dev{n} fused={fused} FAILED: "
-                      f"{r.stderr.strip().splitlines()[-1:]}", file=sys.stderr)
-                continue
+                raise RuntimeError(
+                    f"sharded_sampling dev{n} fused={fused} failed:\n"
+                    + r.stderr)
             for line in r.stdout.strip().splitlines():
                 if line.startswith("sharded_sampling/"):
                     print(line)
@@ -88,12 +104,16 @@ def main(device_counts=(1, 4)) -> None:
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--child", type=int, default=None,
-                    help="(internal) run one measurement on N fake devices")
+                    help="(internal, CPU) run one measurement on N fake "
+                         "devices")
     ap.add_argument("--fused", action="store_true")
-    ap.add_argument("--devices", default="1,4",
-                    help="comma-separated device counts for the sweep")
+    ap.add_argument("--devices", default=None,
+                    help="comma-separated device counts for the sweep "
+                         "(default: 1,4 on the CPU, 1 and all devices on "
+                         "an accelerator)")
     args = ap.parse_args()
     if args.child is not None:
-        _child(args.child, args.fused)
+        _measure(args.child, args.fused)
     else:
-        main(tuple(int(x) for x in args.devices.split(",")))
+        main(tuple(int(x) for x in args.devices.split(","))
+             if args.devices else None)

@@ -22,6 +22,8 @@ import time
 
 import jax
 
+from repro.launch.cache import use_compile_cache
+
 from . import (
     bench_compaction,
     bench_device_serving,
@@ -171,6 +173,7 @@ class _Tee(io.TextIOBase):
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated suite names (default: all)")

@@ -6,7 +6,11 @@ Per (arch × shape × mesh):
     collective = collective_bytes / (chips × link_bw)
 
 All numerators are per-device (the dry-run records per-device HLO costs),
-so the formulas divide by per-chip peaks only. Hardware: TPU v5e.
+so the formulas divide by per-chip peaks only. The peaks come from one
+table keyed by ``device_kind`` (``PEAKS``); a device that is not in it
+is an error, never a default. The dry-runs compile for the production
+TPU v5e mesh (on placeholder CPU devices), so their terms are bounds for
+that target, not measurements.
 
 Also derives MODEL_FLOPS = 6·N·D (dense) / 6·N_active·D (MoE) per device
 and the usefulness ratio MODEL_FLOPS / HLO_FLOPs (catches remat and
@@ -19,6 +23,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import glob
 import json
 import math
@@ -28,10 +33,37 @@ from typing import Dict, Optional
 from repro.configs import get_config, get_shape
 from repro.configs.shapes import apply_shape_policy
 
-# TPU v5e per-chip peaks
-PEAK_FLOPS = 197e12       # bf16 FLOP/s
-HBM_BW = 819e9            # bytes/s
-LINK_BW = 50e9            # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+
+    flops: float   # bf16 FLOP/s
+    hbm_bw: float  # HBM bytes/s
+    ici_bw: float  # chip-to-chip interconnect bytes/s, all links of a chip
+    source: str
+
+
+#: per-chip peaks keyed by ``jax.Device.device_kind``
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s interchip interconnect",
+    ),
+}
+
+#: what the dry-runs (experiments/dryrun/) compile for
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind) -> ChipPeaks:
+    """The ``PEAKS`` row of ``device_kind``; raises for any other device."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"the table has {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                           "experiments", "dryrun")
@@ -93,16 +125,17 @@ def analyze_record(rec: dict) -> dict:
         "bytes_accessed", rec["cost"].get("est_hbm_traffic_bytes", 0.0)
     )
     coll = rec["collectives"]["total_bytes"]
-    t_compute = flops / PEAK_FLOPS
-    t_memory = bytes_acc / HBM_BW
-    t_coll = coll / LINK_BW
+    peaks = peaks_for(DRYRUN_DEVICE_KIND)
+    t_compute = flops / peaks.flops
+    t_memory = bytes_acc / peaks.hbm_bw
+    t_coll = coll / peaks.ici_bw
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops_per_device(rec["arch"], rec["shape"], rec["devices"])
     ratio = mf["model_flops_per_device"] / flops if flops else float("nan")
     bound_time = max(terms.values())
     mfu_bound = (
-        mf["model_flops_per_device"] / PEAK_FLOPS / bound_time
+        mf["model_flops_per_device"] / peaks.flops / bound_time
         if bound_time else float("nan")
     )
     return {
@@ -132,16 +165,16 @@ def score_eval_markdown(artifact: Optional[dict] = None) -> str:
 
     Each row of ``experiments/score_eval/BENCH_score_eval.json`` carries
     the per-NFE model FLOPs/bytes (baseline-path AOT cost analysis) and
-    the measured per-NFE wall time; this join divides by the TPU v5e
-    peaks to classify each score eval as compute- or memory-bound and —
-    when the record came from an accelerator — reports achieved FLOP/s
-    as a fraction of peak. CPU records keep the bound classification
-    (it depends only on the model cost) but their ``achieved`` column
-    reflects interpreter-mode wall time, flagged in the footer.
+    the measured per-NFE wall time; this join divides by the peaks of
+    the device that made the record (its ``device_kind``) to classify
+    each score eval as compute- or memory-bound and report achieved
+    FLOP/s as a fraction of peak. A record from a device without a
+    ``PEAKS`` row (the CPU among them) raises.
     """
     if artifact is None:
         with open(SCORE_EVAL_ARTIFACT) as f:
             artifact = json.load(f)
+    peaks = peaks_for(artifact.get("device_kind"))
     header = ("workload", "preset", "variant", "us/NFE", "GFLOP/NFE",
               "t_compute_s", "t_memory_s", "bound", "achieved_GFLOP/s",
               "frac_peak")
@@ -149,24 +182,21 @@ def score_eval_markdown(artifact: Optional[dict] = None) -> str:
     for r in artifact["rows"]:
         flops = float(r.get("flops_per_nfe") or 0.0)
         byts = float(r.get("bytes_per_nfe") or 0.0)
-        t_c = flops / PEAK_FLOPS
-        t_m = byts / HBM_BW
+        t_c = flops / peaks.flops
+        t_m = byts / peaks.hbm_bw
         bound = "compute" if t_c >= t_m else "memory"
         us = float(r["us_per_call"])
         achieved = flops / (us * 1e-6) if us else 0.0
         lines.append("| " + " | ".join((
             r["workload"], r["preset"], r["variant"], f"{us:.1f}",
             f"{flops / 1e9:.2f}", f"{t_c:.3e}", f"{t_m:.3e}", bound,
-            f"{achieved / 1e9:.2f}", f"{achieved / PEAK_FLOPS:.2e}",
+            f"{achieved / 1e9:.2f}", f"{achieved / peaks.flops:.2e}",
         )) + " |")
-    backend = artifact.get("backend", "?")
     lines.append("")
     lines.append(
-        f"_backend: {backend}; peaks: TPU v5e "
-        f"{PEAK_FLOPS / 1e12:.0f} TFLOP/s bf16, {HBM_BW / 1e9:.0f} GB/s HBM._"
-        + (" _CPU interpreter-mode wall times — achieved/frac_peak are "
-           "plumbing-validation numbers, not hardware measurements._"
-           if backend == "cpu" else ""))
+        f"_device: {artifact['device_kind']}; peaks: "
+        f"{peaks.flops / 1e12:.0f} TFLOP/s bf16, "
+        f"{peaks.hbm_bw / 1e9:.0f} GB/s HBM ({peaks.source})._")
     return "\n".join(lines)
 
 

@@ -80,6 +80,16 @@ DIT_100M = DiTConfig(
     num_heads=12, d_ff=3072,
 )
 
+#: the serving launcher's default: 8×8, width 32, 2 layers — small
+#: enough that CPU tests and demos of the serve loop stay fast
+SMALL_DIT = DiTConfig(
+    image_size=8, channels=3, patch=4, d_model=32, num_layers=2,
+    num_heads=2, d_ff=64,
+)
+
+#: DiTs ``launch/serve --diffusion --net NAME`` can serve
+DIT_NETS = {"small": SMALL_DIT, "cifar": CIFAR_DIT, "highres": HIGHRES_DIT}
+
 TOY_MLP = MLPScoreConfig(dim=2, hidden=128, depth=3)
 
 # Trajectory-diffusion planning workload (DESIGN.md §10): horizon-32

@@ -148,7 +148,6 @@ def sharded_error_step(
     so each device reads only its own slots' ε (DESIGN.md §14).
     """
     from repro.parallel.collectives import scaled_error_l2_psum
-    from repro.parallel.compat import shard_map
 
     interpret = _on_cpu() if interpret is None else interpret
     batch_axes = (batch_axes,) if isinstance(batch_axes, str) else tuple(batch_axes)
@@ -187,12 +186,12 @@ def sharded_error_step(
     state_spec = P(batch_axes, feature_axis)
     coeff_spec = P(batch_axes)
     n_eps = 2 if vec_eps else 0
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(state_spec,) * 5 + (coeff_spec,) * (3 + n_eps),
         out_specs=(state_spec, coeff_spec),
-        check_rep=False,  # no replication rule for pallas_call
+        check_vma=False,  # pallas_call has no varying-axis rule
     )
     operands = (xf, xpf, s2f, zf, xvf, e0, d1, d2)
     if vec_eps:

@@ -31,6 +31,7 @@ import jax
 from repro.analysis.hlo import collective_bytes_from_text, summarize_cost
 from repro.configs import ARCH_IDS, get_config, get_shape
 from repro.configs.shapes import SHAPES
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_dryrun
 
@@ -40,7 +41,7 @@ OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
 
 def _compile_spec(cfg, shape, mesh, remat, unroll):
     spec = build_dryrun(cfg, shape, mesh, remat=remat, unroll=unroll)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             spec.fn,
             in_shardings=spec.in_shardings,
@@ -167,6 +168,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=sorted(SHAPES))

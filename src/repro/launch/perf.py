@@ -25,8 +25,10 @@ import time
 import jax
 
 from repro.analysis.hlo import collective_bytes_from_text, summarize_cost
+from repro.analysis.roofline import DRYRUN_DEVICE_KIND, peaks_for
 from repro.configs import ARCH_IDS, get_config, get_shape
 from repro.configs.shapes import SHAPES
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_dryrun
 
@@ -78,7 +80,7 @@ def run_variant(arch: str, shape_name: str, variant: str,
             c, shape, mesh, unroll=unroll,
             **{k: v for k, v in kw.items()},
         )
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(spec.fn, in_shardings=spec.in_shardings,
                              out_shardings=spec.out_shardings,
                              donate_argnums=spec.donate_argnums)
@@ -119,10 +121,11 @@ def run_variant(arch: str, shape_name: str, variant: str,
         "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
         "wall_s": round(time.time() - t0, 1),
     }
-    # roofline terms (v5e)
-    rec["t_compute_s"] = rec["flops"] / 197e12
-    rec["t_memory_s"] = rec["est_hbm_traffic_bytes"] / 819e9
-    rec["t_collective_s"] = rec["collective_bytes"] / 50e9
+    # roofline terms for the chip the dry-run compiles for
+    peaks = peaks_for(DRYRUN_DEVICE_KIND)
+    rec["t_compute_s"] = rec["flops"] / peaks.flops
+    rec["t_memory_s"] = rec["est_hbm_traffic_bytes"] / peaks.hbm_bw
+    rec["t_collective_s"] = rec["collective_bytes"] / peaks.ici_bw
     terms = {k: rec[f"t_{k}_s"] for k in ("compute", "memory", "collective")}
     rec["dominant"] = max(terms, key=terms.get)
 
@@ -142,6 +145,7 @@ def run_variant(arch: str, shape_name: str, variant: str,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--shape", choices=sorted(SHAPES), required=True)

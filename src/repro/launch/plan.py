@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from repro.core import AdaptiveConfig, VPSDE, sample
 from repro.core.analytic import class_gaussian_noise_pred, gaussian_score
 from repro.core.precision import PRESETS, resolve_policy
+from repro.launch.cache import use_compile_cache
 from repro.planning import (
     PlannerConfig, RecedingHorizonPlanner, get_env,
 )
@@ -162,6 +163,7 @@ def compare_em(horizon: int = 8, dim: int = 4, batch: int = 64,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--env", default="ou", choices=["ou", "pointmass"])
     ap.add_argument("--envs", type=int, default=6)

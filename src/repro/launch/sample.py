@@ -59,6 +59,8 @@ from repro.configs.diffusion import CIFAR_DIT, HIGHRES_DIT
 from repro.core import VESDE, VPSDE, AdaptiveConfig, sample
 from repro.core.precision import PRESETS, resolve_policy
 from repro.core.solvers.adaptive import SolverCarry, solve_chunk
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_data_mesh
 from repro.models.dit import DiTConfig, dit_forward, init_dit, make_score_fn
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
@@ -274,7 +276,7 @@ def dryrun(multi_pod: bool, batch: int = 512, pipeline: bool = False,
                             AdaptiveConfig(eps_rel=0.02, precision=precision),
                             forward_fn=fwd)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(
             step, in_shardings=(p_shard, s_shard), out_shardings=s_shard,
             donate_argnums=(1,),
@@ -324,7 +326,7 @@ def dryrun_loop(batch: int = 256, precision: str = "fp32") -> dict:
     net = CIFAR_DIT
     sde = VPSDE()
     ndev = len(jax.devices())
-    mesh = jax.make_mesh((ndev,), ("data",))
+    mesh = make_data_mesh()
     assert batch % ndev == 0, f"batch {batch} must divide {ndev} devices"
     policy = resolve_policy(precision)
 
@@ -457,6 +459,7 @@ def demo_inpaint(precision: str = "fp32") -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dryrun", action="store_true")
     ap.add_argument("--dryrun-loop", action="store_true",

@@ -47,7 +47,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
+from repro.configs.diffusion import DIT_NETS
 from repro.core.precision import PRESETS
+from repro.launch.cache import use_compile_cache
 from repro.launch.steps import make_serve_step
 from repro.models import init_decode_state, init_model
 from repro.models.config import ModelConfig
@@ -86,7 +88,8 @@ def serve_batch(
     return jnp.concatenate(out, axis=1)
 
 
-def serve_diffusion(*, slots: int, requests: int, image_size: int = 8,
+def serve_diffusion(*, slots: int, requests: int, net: str = "small",
+                    devices: int | None = None,
                     sync_horizon: int = 4, compaction: bool = True,
                     precision: str = "fp32", inpaint: bool = False,
                     cfg_scale: float | None = None,
@@ -98,13 +101,18 @@ def serve_diffusion(*, slots: int, requests: int, image_size: int = 8,
                     trace_out: str | None = None) -> dict:
     """Continuous-batching diffusion serving on the ambient device set.
 
-    Builds a data-parallel mesh over every available device, shards the
-    slot batch across it, and drains ``requests`` prior-seeded requests
-    through a small DiT score net with the horizon-chunked solver:
+    Builds a data-parallel mesh over the first ``devices`` devices (all
+    by default), shards the slot batch across it, and drains
+    ``requests`` prior-seeded requests through the DiT ``net`` names in
+    ``configs.diffusion.DIT_NETS`` (random weights from seed 0, with the
+    zero-init leaves filled by ``liven_dit`` so the score is not
+    identically 0) with the horizon-chunked solver:
     ``sync_horizon`` Algorithm-1 iterations per host round-trip, with
     converged slots retired and refilled at every sync (DESIGN.md §7).
     Returns (and prints) throughput, the wasted-NFE fraction, and the
-    per-device refill counts that evidence shard-local compaction.
+    per-device refill counts that evidence shard-local compaction; the
+    record's ``batcher`` entry is the drained ``DiffusionBatcher``
+    (delivered requests in ``batcher.finished``).
 
     ``device_resident=True`` (DESIGN.md §12) runs the on-device serve
     loop instead: retirement polling, compaction, and admission execute
@@ -134,11 +142,14 @@ def serve_diffusion(*, slots: int, requests: int, image_size: int = 8,
     ``trace_record()`` (requests, metrics, spans, step history) as JSON
     — the input of ``repro.analysis.telemetry``'s markdown report.
     """
+    import dataclasses
+
     from repro.core import AdaptiveConfig, VPSDE
     from repro.core.guidance import ClassifierFree, Inpaint
     from repro.core.precision import resolve_policy
+    from repro.launch.mesh import make_data_mesh
     from repro.launch.sample import make_sample_step
-    from repro.models.dit import DiTConfig, init_dit
+    from repro.models.dit import init_dit, liven_dit
     from repro.observability.tracing import StageTracer
     from repro.serving.diffusion_server import DiffusionBatcher, ImageRequest
     from repro.serving.scheduler import EdfPriorityAdmission
@@ -146,11 +157,12 @@ def serve_diffusion(*, slots: int, requests: int, image_size: int = 8,
     if inpaint and cfg_scale is not None:
         raise ValueError("pick one conditioner per server: "
                          "--inpaint or --cfg-scale")
-    ndev = jax.device_count()
-    mesh = jax.make_mesh((ndev,), ("data",))
+    mesh = make_data_mesh(devices)
+    ndev = mesh.size
     num_classes = 10 if cfg_scale is not None else 0
-    net = DiTConfig(image_size=image_size, patch=4, d_model=32, num_layers=2,
-                    num_heads=2, d_ff=64, num_classes=num_classes)
+    net_name = net
+    net = dataclasses.replace(DIT_NETS[net_name], num_classes=num_classes)
+    image_size = net.image_size
     sde = VPSDE()
     policy = resolve_policy(precision)
     conditioner = None
@@ -162,7 +174,10 @@ def serve_diffusion(*, slots: int, requests: int, image_size: int = 8,
                          conditioner=conditioner)
     # weights stored at the policy's param dtype; the per-device weight
     # HBM and weight-broadcast bytes halve under bf16_full
-    params = policy.cast_params(init_dit(net, jax.random.PRNGKey(0)))
+    k_init, k_live = jax.random.split(jax.random.PRNGKey(0))
+    # one program: an eager init compiles every op of it on its own
+    params = jax.jit(lambda: policy.cast_params(
+        liven_dit(init_dit(net, k_init), k_live)))()
     step = make_sample_step(net, sde, cfg)
     shape = (image_size, image_size, net.channels)
     tiered = tier is not None
@@ -207,6 +222,7 @@ def serve_diffusion(*, slots: int, requests: int, image_size: int = 8,
     dt = time.time() - t0
     nfes = [done[u].nfe for u in sorted(done)]
     rec = {
+        "net": net_name,
         "devices": ndev,
         "slots": slots,
         "slots_per_device": b.slots_per_device,
@@ -231,6 +247,7 @@ def serve_diffusion(*, slots: int, requests: int, image_size: int = 8,
         "telemetry": telemetry,
         "metrics_out": metrics_out,
         "trace_out": trace_out,
+        "batcher": b,
     }
     if metrics_out:
         import json
@@ -251,7 +268,7 @@ def serve_diffusion(*, slots: int, requests: int, image_size: int = 8,
         path.parent.mkdir(parents=True, exist_ok=True)
         path.write_text(json.dumps(b.trace_record(), indent=2) + "\n")
         print(f"trace -> {path}")
-    print(f"diffusion serve[{policy.name}, {rec['conditioner']}"
+    print(f"diffusion serve[{net_name}, {policy.name}, {rec['conditioner']}"
           f"{', device-resident' if device_resident else ''}]: "
           f"{rec['completed']}/{requests} requests in {dt:.1f}s "
           f"({rec['samples_per_sec']:.2f} samples/s) on {ndev} device(s), "
@@ -271,6 +288,7 @@ def serve_diffusion(*, slots: int, requests: int, image_size: int = 8,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--reduced", action="store_true")
@@ -295,6 +313,9 @@ def main() -> None:
                          "instead of the analytic one")
     ap.add_argument("--fake-devices", type=int, default=None,
                     help="force N placeholder host devices (set pre-init)")
+    ap.add_argument("--net", default="small", choices=sorted(DIT_NETS),
+                    help="DiT score net to serve (diffusion mode; "
+                         "configs.diffusion.DIT_NETS)")
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--sync-horizon", type=int, default=4,
@@ -349,6 +370,7 @@ def main() -> None:
         return
     if args.diffusion:
         serve_diffusion(slots=args.slots, requests=args.requests,
+                        net=args.net,
                         sync_horizon=args.sync_horizon,
                         compaction=not args.no_compaction,
                         precision=args.precision,

@@ -37,6 +37,8 @@ import numpy as np
 
 from repro.core import AdaptiveConfig, VPSDE, sample
 from repro.core.analytic import gaussian_noise_pred, gaussian_score
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_data_mesh, make_mesh
 
 
 def check_sample_equivalence(mesh, *, fused: bool) -> dict:
@@ -188,9 +190,10 @@ def check_device_resident(mesh) -> dict:
 
 
 def main() -> int:
+    use_compile_cache()
     ndev = jax.device_count()
-    mesh = jax.make_mesh((ndev,), ("data",))
-    mesh2d = jax.make_mesh((ndev // 2, 2), ("data", "model"))
+    mesh = make_data_mesh()
+    mesh2d = make_mesh((ndev // 2, 2), ("data", "model"))
     results = {
         "devices": ndev,
         "sample_jnp": check_sample_equivalence(mesh, fused=False),

@@ -17,6 +17,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.cache import use_compile_cache
 from repro.checkpoint import save_checkpoint
 from repro.configs import ARCH_IDS, get_config
 from repro.data.tokens import TokenPipelineConfig, synth_batch
@@ -44,7 +45,7 @@ def train_loop(
     optimizer = AdamW(lr=warmup_cosine(lr, max(steps // 10, 1), steps))
     key = jax.random.PRNGKey(seed)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         p_shard = param_shardings(
             jax.eval_shape(lambda k: init_model(cfg, k), key),
             mesh,
@@ -89,6 +90,7 @@ def train_loop(
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--reduced", action="store_true",

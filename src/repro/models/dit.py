@@ -125,6 +125,29 @@ def init_dit(cfg: DiTConfig, key: Array) -> Dict[str, Any]:
     }
 
 
+def liven_dit(params: Dict[str, Any], key: Array,
+              std: float = 0.02) -> Dict[str, Any]:
+    """Fill the zero-init leaves (``ada``, ``final_ada``, ``patch_out``)
+    with N(0, std²) noise drawn from ``key``.
+
+    ``init_dit`` zeroes them (adaLN-Zero), so a fresh DiT outputs
+    exactly 0 and any comparison of two forwards passes vacuously. Use
+    this for random weights that are served or compared.
+    """
+    k_ada, k_final, k_out = jax.random.split(key, 3)
+
+    def noise(k, w):
+        return (std * jax.random.normal(k, w.shape, jnp.float32)).astype(w.dtype)
+
+    return {
+        **params,
+        "layers": {**params["layers"],
+                   "ada": noise(k_ada, params["layers"]["ada"])},
+        "final_ada": noise(k_final, params["final_ada"]),
+        "patch_out": noise(k_out, params["patch_out"]),
+    }
+
+
 def _patchify(x: Array, cfg: DiTConfig) -> Array:
     B, H, W, C = x.shape
     p = cfg.patch

@@ -106,13 +106,9 @@ def profiler_annotation(name: str, step: Optional[int] = None):
     """A ``jax.profiler`` trace-annotation context for the given stage:
     ``StepTraceAnnotation`` when a step number is given (so profiler
     UIs group the donated driver's windows), ``TraceAnnotation``
-    otherwise. Both are cheap no-ops without an active profiler; falls
-    back to a null context if the profiler API is unavailable."""
-    try:
-        import jax
+    otherwise. Both are cheap no-ops without an active profiler."""
+    import jax
 
-        if step is not None:
-            return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+    if step is not None:
+        return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
+    return jax.profiler.TraceAnnotation(name)

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel import compat as _compat
-
 Array = jax.Array
 
 
@@ -55,7 +53,7 @@ def _local_write_and_attend(
     n = 1
     my_index = jnp.zeros((), jnp.int32)
     for a in axis:
-        sz = _compat.axis_size(a)
+        sz = jax.lax.axis_size(a)
         my_index = my_index * sz + jax.lax.axis_index(a).astype(jnp.int32)
         n = n * sz
     Sc = Scl * n
@@ -127,10 +125,8 @@ def flash_decode(
         _local_write_and_attend,
         axis=axis, window=window, softcap=softcap, group=group,
     )
-    # Resolve the ambient mesh: the launchers use the legacy `with mesh:`
-    # context, which jax.shard_map's context-mesh lookup doesn't see.
-    mesh = _compat.ambient_mesh()
-    fn = _compat.shard_map(
+    # mesh=None: jax.shard_map takes the mesh set by ``jax.set_mesh``
+    fn = jax.shard_map(
         body,
         in_specs=(
             P(), P(), P(),                       # q, k_new, v_new replicated over axis
@@ -142,6 +138,5 @@ def flash_decode(
         out_specs=(P(), P(None, axis, None, None),
                    P(None, axis, None, None), P(axis)),
         axis_names=set(axis),
-        mesh=mesh,
     )
     return fn(q, k_new, v_new, cache_k, cache_v, pos, length)

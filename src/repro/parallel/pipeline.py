@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel import compat as _compat
-
 Array = jax.Array
 
 
@@ -38,7 +36,7 @@ def _pipeline_local(params_local, x_mb: Array, *, body: Callable,
     x_mb: (M, mb, ...) microbatches — input on stage 0, ignored elsewhere.
     Returns (M, mb, ...) outputs — valid on the LAST stage.
     """
-    n = _compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     M = num_microbatches
     ticks = M + n - 1
@@ -69,8 +67,8 @@ def _pipeline_local(params_local, x_mb: Array, *, body: Callable,
         return (in_buf_next, outputs), None
 
     init = (
-        _compat.pvary(zeros, (axis,)),
-        _compat.pvary(jnp.zeros_like(x_mb), (axis,)),
+        jax.lax.pcast(zeros, (axis,), to="varying"),
+        jax.lax.pcast(jnp.zeros_like(x_mb), (axis,), to="varying"),
     )
     (_, outputs), _ = jax.lax.scan(tick_fn, init, jnp.arange(ticks))
     # broadcast the last stage's outputs to every stage (tiny psum trick:
@@ -98,10 +96,8 @@ def pipeline_forward(
     assert B % num_microbatches == 0, (B, num_microbatches)
     x_mb = x.reshape((num_microbatches, B // num_microbatches) + x.shape[1:])
 
-    if mesh is None:
-        mesh = _compat.ambient_mesh()
-
-    fn = _compat.shard_map(
+    # mesh=None: jax.shard_map takes the mesh set by ``jax.set_mesh``
+    fn = jax.shard_map(
         functools.partial(
             _pipeline_local, body=body, axis=axis,
             num_microbatches=num_microbatches,

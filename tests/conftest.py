@@ -1,27 +1,18 @@
-import os
-
 import jax
 import pytest
+
+from repro.launch.cache import use_compile_cache
+
+# The default suite is jit-compile dominated, so persist XLA's
+# compilation cache across runs: a warm `pytest -q` re-run skips most
+# compiles. Numerics are unaffected — the cache stores compiled
+# executables keyed on the exact HLO, compile options and compiler
+# version.
+use_compile_cache()
 
 # Smoke tests and benches must see the single real CPU device — the 512
 # placeholder devices are requested by dryrun.py only (in subprocesses).
 jax.config.update("jax_platform_name", "cpu")
-
-# The default suite is jit-compile dominated, so persist XLA's
-# compilation cache across runs: a warm `pytest -q` re-run skips most
-# compiles (CI caches the directory keyed on the JAX version). Numerics
-# are unaffected — the cache stores compiled executables keyed on the
-# exact HLO + compile options. The installed jax/jaxlib version pair is
-# part of the directory key: a dependency bump starts a clean
-# subdirectory instead of accreting dead entries (stale executables
-# never hit — XLA keys on its own compiler version — but they would
-# bloat the CI cache archive forever).
-_CACHE_DIR = os.environ.get(
-    "REPRO_JAX_CACHE",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache", f"jax-{jax.__version__}-{jax.lib.__version__}"),
-)
-jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 

@@ -40,6 +40,7 @@ from repro.core.analytic import (
     gaussian_w2,
 )
 from repro.core.guidance import cond_batch, gray_basis, to_gray
+from repro.launch.mesh import make_data_mesh
 
 MU, S0 = 0.3, 0.5
 BATCH, DIM = 64, 8
@@ -284,7 +285,7 @@ def test_solver_carry_shardings_cover_cond_leaves():
     from repro.core.guidance import Inpaint
     from repro.parallel.sharding import solver_carry_shardings
 
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_data_mesh()
     struct = Inpaint().cond_struct(8, (DIM,))
     s = solver_carry_shardings(mesh, 8, 2, per_slot_keys=True, cond=struct)
     assert set(s.cond) == {"mask", "observed"}

@@ -39,7 +39,7 @@ def test_flash_decode_matches_teacher_forcing(axis, rng):
     want = _teacher_forced(cfg, params, toks)
 
     cfg_fd = cfg.replace(decode_flash_shard=axis)
-    with make_host_mesh():
+    with jax.set_mesh(make_host_mesh()):
         st = init_decode_state(cfg_fd, 2, cache_len=12)
         step = jax.jit(lambda p, t, s: decode_step(p, t, s, cfg_fd))
         outs = []
@@ -59,7 +59,7 @@ def test_flash_decode_ring_wraparound(rng):
     toks = jax.random.randint(rng, (1, 12), 0, cfg.vocab_size)
     want = _teacher_forced(cfg, params, toks)
     cfg_fd = cfg.replace(decode_flash_shard="model")
-    with make_host_mesh():
+    with jax.set_mesh(make_host_mesh()):
         st = init_decode_state(cfg_fd, 1, cache_len=4)  # < seq len → wraps
         step = jax.jit(lambda p, t, s: decode_step(p, t, s, cfg_fd))
         outs = []
@@ -107,7 +107,7 @@ def test_seq_shard_constraint_is_noop_on_host_mesh(rng):
     cfg_sp = cfg.replace(attn_q_seq_shard="model", residual_seq_shard="model")
     params = init_model(cfg, rng)
     toks = jax.random.randint(rng, (2, 8), 0, cfg.vocab_size)
-    with make_host_mesh():
+    with jax.set_mesh(make_host_mesh()):
         l0, _ = jax.jit(lambda p, t: forward(p, t, cfg))(params, toks)
         l1, _ = jax.jit(lambda p, t: forward(p, t, cfg_sp))(params, toks)
     np.testing.assert_allclose(np.asarray(l0), np.asarray(l1),

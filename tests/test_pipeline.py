@@ -39,7 +39,7 @@ def test_single_stage_equals_scan(microbatches, rng):
     params = _stacked_mlp(rng, R, d)
     x = jax.random.normal(jax.random.fold_in(rng, 1), (B, d))
     want = _reference(params, x)
-    with make_host_mesh():  # data axis size 1 → one pipeline stage
+    with jax.set_mesh(make_host_mesh()):  # data axis size 1 → one pipeline stage
         got = pipeline_forward(params, x, _body, axis="data",
                                num_microbatches=microbatches)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -50,7 +50,7 @@ def test_pipeline_is_jittable(rng):
     R, d, B = 2, 8, 4
     params = _stacked_mlp(rng, R, d)
     x = jax.random.normal(rng, (B, d))
-    with make_host_mesh():
+    with jax.set_mesh(make_host_mesh()):
         fn = jax.jit(lambda p, x: pipeline_forward(
             p, x, _body, axis="data", num_microbatches=2))
         got = fn(params, x)

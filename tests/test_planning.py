@@ -18,6 +18,7 @@ from repro.core.analytic import (
 )
 from repro.core.sampling import solve_in_chunks
 from repro.core.solvers.adaptive import adaptive
+from repro.launch.mesh import make_data_mesh
 from repro.models.temporal_unet import (
     TemporalUNetConfig, init_temporal_unet, make_score_fn,
     temporal_unet_forward,
@@ -333,7 +334,7 @@ def test_solver_carry_shardings_cover_plan_payload():
     gets a batch-axis spec of its own ndim (DESIGN.md §10)."""
     from repro.parallel.sharding import solver_carry_shardings
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     c = PlanConditioner(scale=1.5)
     struct = c.cond_struct(4, PCFG.sample_shape)
     sh = solver_carry_shardings(mesh, 4, 3, per_slot_keys=True, cond=struct)

@@ -21,7 +21,9 @@ from repro.core import (
     solve_in_chunks,
 )
 from repro.core.analytic import gaussian_score
-from repro.models.dit import DiTConfig, dit_forward, init_dit, make_score_fn
+from repro.models.dit import (
+    DiTConfig, dit_forward, init_dit, liven_dit, make_score_fn,
+)
 
 MU, S0 = 0.3, 0.5
 
@@ -173,7 +175,7 @@ def test_bf16_policy_smoke(rng):
     net = DiTConfig(image_size=8, patch=4, d_model=32, num_layers=2,
                     num_heads=2, d_ff=64)
     sde = VPSDE()
-    params = init_dit(net, rng)
+    params = liven_dit(init_dit(net, rng), rng)
     x = jax.random.normal(rng, (4, 8, 8, 3))
     t = jnp.full((4,), 0.5)
 

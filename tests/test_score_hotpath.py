@@ -28,7 +28,7 @@ import pytest
 
 from repro.core.precision import resolve_policy
 from repro.models.attention import _ref_attention, attention
-from repro.models.dit import DiTConfig, dit_forward, init_dit
+from repro.models.dit import DiTConfig, dit_forward, init_dit, liven_dit
 from repro.models.temporal_unet import (
     TemporalUNetConfig, _groupnorm, _gn_silu, init_temporal_unet,
     temporal_unet_forward,
@@ -110,7 +110,7 @@ def test_dit_flash_parity(preset, rng):
     cfg1 = dataclasses.replace(cfg0, use_flash=True)
     assert cfg0.use_flash is False  # flag defaults off
     policy = resolve_policy(preset)
-    params = policy.cast_params(init_dit(cfg0, rng))
+    params = policy.cast_params(liven_dit(init_dit(cfg0, rng), rng))
     x = jax.random.normal(rng, (2, 16, 16, 3))
     t = jnp.linspace(0.1, 1.0, 2)
     base = dit_forward(params, x, t, cfg0, policy=policy)
@@ -125,7 +125,7 @@ def test_dit_flash_token_padding(rng):
     cfg0 = DiTConfig(image_size=8, patch=4, d_model=32, num_layers=1,
                      num_heads=4, d_ff=64)
     cfg1 = dataclasses.replace(cfg0, use_flash=True)
-    params = init_dit(cfg0, rng)
+    params = liven_dit(init_dit(cfg0, rng), rng)
     x = jax.random.normal(rng, (2, 8, 8, 3))
     t = jnp.linspace(0.1, 1.0, 2)
     base = dit_forward(params, x, t, cfg0)

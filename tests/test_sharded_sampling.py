@@ -26,6 +26,7 @@ import pytest
 
 from repro.core import AdaptiveConfig, VPSDE, sample
 from repro.core.analytic import gaussian_noise_pred, gaussian_score
+from repro.launch.mesh import make_data_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -43,7 +44,7 @@ def _score(sde):
 
 def test_sample_mesh_1device_bitwise_noop():
     sde = VPSDE()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     key = jax.random.PRNGKey(0)
     cfg = AdaptiveConfig(eps_rel=0.05)
     ref = jax.jit(lambda k: sample(sde, _score(sde), (4, 32), k, config=cfg))(key)
@@ -58,7 +59,7 @@ def test_sample_mesh_indivisible_batch_replicates():
     # batch 3 on a 1-device mesh: batch_sharding falls back to replication
     # and sampling still works (the guard for batch % devices != 0).
     sde = VPSDE()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     res = sample(sde, _score(sde), (3, 16), jax.random.PRNGKey(1),
                  config=AdaptiveConfig(eps_rel=0.1), mesh=mesh)
     assert bool(jnp.all(jnp.isfinite(res.x)))
@@ -69,7 +70,7 @@ def test_adaptive_accepts_replicated_sharding():
     # not crash (regression: IndexError on sharding.spec[0])
     from repro.parallel.sharding import replicated
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     sde = VPSDE()
     res = sample(sde, _score(sde), (2, 16), jax.random.PRNGKey(0),
                  config=AdaptiveConfig(eps_rel=0.1, use_fused_kernel=True),
@@ -80,7 +81,7 @@ def test_adaptive_accepts_replicated_sharding():
 def test_sharded_error_step_1device_matches():
     from repro.kernels.solver_step import ops
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     ks = jax.random.split(jax.random.PRNGKey(2), 8)
     B, shape = 4, (4, 6, 5)  # D=30: exercises lane padding
     x, xp, s2, z, xv = (jax.random.normal(k, shape) for k in ks[:5])
@@ -105,7 +106,7 @@ def test_batcher_mesh_1device():
                     num_heads=1, d_ff=8)
     step = make_sample_step(net, sde, cfg,
                             forward_fn=gaussian_noise_pred(sde, MU, S0))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_data_mesh(1)
     b = DiffusionBatcher(sde, step, params=None, sample_shape=(16,),
                          slots=4, cfg=cfg, mesh=mesh)
     for uid in range(8):
