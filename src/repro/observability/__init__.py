@@ -29,7 +29,6 @@ from repro.observability.tracing import (
     NULL_TRACER,
     NullTracer,
     StageTracer,
-    profiler_annotation,
 )
 
 __all__ = [
@@ -43,7 +42,6 @@ __all__ = [
     "feature_moments",
     "frechet_from_moments",
     "init_telemetry",
-    "profiler_annotation",
     "proxy_fid",
     "random_feature_extractor",
     "record_step",

@@ -1,14 +1,19 @@
-"""Serve-loop span tracing (DESIGN.md §15): monotonic-clock spans over
-the batcher's admission/solve/delivery stages and planner rounds, plus
-``jax.profiler`` annotation hooks around the jitted device programs.
+"""Serve-loop span tracing (DESIGN.md §15): spans over every host visit
+of the batcher between device programs, and over the planner's rounds
+and request construction.
 
-The tracer is deliberately minimal — a list of ``{name, start, end,
-duration_s, attrs}`` dicts on an injectable monotonic clock — because
-the interesting structure (request-id propagation through compaction,
-per-stage latency distributions) lives in the *attrs* the serve loop
-attaches, not in the recording machinery. ``NULL_TRACER`` is the
-default no-op: its ``span`` yields without recording, so an untraced
-batcher does no clock reads and allocates nothing per stage.
+Each span is recorded twice, on two clocks: as a ``{name, id, parent,
+start, end, duration_s, attrs}`` dict on the tracer's injectable
+monotonic clock, and as a ``jax.profiler.TraceAnnotation`` under its
+bare name, so that with a profiler running it lands in the trace's
+host plane on the device's clock, beside the programs it dispatched
+(without one the annotation is a cheap no-op). ``parent`` is the id of
+the span open around it, so a stage's self time is its duration less
+its children's. The interesting structure (request-id propagation
+through compaction, per-stage latency distributions) lives in the
+*attrs* the serve loop attaches. ``NULL_TRACER`` is the default no-op:
+its ``span`` yields without recording, so an untraced batcher reads no
+clock, opens no annotation and records nothing.
 """
 
 from __future__ import annotations
@@ -17,6 +22,8 @@ import bisect
 import contextlib
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+import jax
 
 #: log-spaced latency bucket upper bounds (seconds) for the per-stage
 #: histograms; the final implicit bucket is +Inf
@@ -28,27 +35,38 @@ LATENCY_BUCKETS_S = (
 class StageTracer:
     """Span recorder: ``with tracer.span("serve/solve", window=3): ...``.
 
-    Spans nest freely (the record is a flat list ordered by end time);
-    attrs must be JSON-serializable — the serve loop passes request
-    uids, slot indices, and per-request NFE lists so a trace reconciles
-    against the device-side counters (DESIGN.md §15).
+    Spans nest (the record is a flat list ordered by end time, each
+    naming its enclosing span's ``id`` as ``parent``); attrs must be
+    JSON-serializable — the serve loop passes request uids, slot
+    indices, and per-request NFE lists so a trace reconciles against
+    the device-side counters (DESIGN.md §15). Attrs stay out of the
+    profiler annotation's name, which is the bare span name.
     """
 
-    #: False only on the null tracer — the serve loop keys optional
-    #: extras (profiler annotations, attr assembly) on this flag
+    #: False only on the null tracer — the serve loop assembles span
+    #: attrs only when this is set
     enabled = True
 
     def __init__(self, clock: Optional[Callable[[], float]] = None):
         self.clock = clock if clock is not None else time.monotonic
         self.spans: List[Dict[str, Any]] = []
+        self._open: List[int] = []  # ids of the spans open, innermost last
+        self._next_id = 0
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        rec: Dict[str, Any] = {"name": name, "start": self.clock(),
-                               "attrs": attrs}
+        rec: Dict[str, Any] = {
+            "name": name, "id": self._next_id,
+            "parent": self._open[-1] if self._open else None,
+            "start": self.clock(), "attrs": attrs,
+        }
+        self._next_id += 1
+        self._open.append(rec["id"])
         try:
-            yield rec
+            with jax.profiler.TraceAnnotation(name):
+                yield rec
         finally:
+            self._open.pop()
             rec["end"] = self.clock()
             rec["duration_s"] = rec["end"] - rec["start"]
             self.spans.append(rec)
@@ -84,9 +102,9 @@ class StageTracer:
 
 
 class NullTracer(StageTracer):
-    """The no-op default: ``span`` records nothing and reads no clock —
-    an untraced serve loop pays one ``is not None``-grade check per
-    stage and keeps its pre-§15 behaviour exactly."""
+    """The no-op default: ``span`` records nothing, reads no clock and
+    opens no profiler annotation — an untraced serve loop keeps its
+    pre-§15 behaviour exactly."""
 
     enabled = False
 
@@ -100,15 +118,3 @@ class NullTracer(StageTracer):
 
 #: shared no-op instance (stateless — safe to share across batchers)
 NULL_TRACER = NullTracer()
-
-
-def profiler_annotation(name: str, step: Optional[int] = None):
-    """A ``jax.profiler`` trace-annotation context for the given stage:
-    ``StepTraceAnnotation`` when a step number is given (so profiler
-    UIs group the donated driver's windows), ``TraceAnnotation``
-    otherwise. Both are cheap no-ops without an active profiler."""
-    import jax
-
-    if step is not None:
-        return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
-    return jax.profiler.TraceAnnotation(name)

@@ -327,23 +327,25 @@ class RecedingHorizonPlanner:
         the server conditioner's own ``cond_struct``: the pin mask /
         observation for this env's current state and/or its returns bin
         (None → the null label) — so Inpaint-only and CFG-only
-        conditioners get exactly the keys they declare."""
-        struct = self.cfg.conditioner.cond_struct(1, self.pcfg.sample_shape)
-        if returns_label is not None and "label" not in struct:
-            raise ValueError(
-                f"returns_label={returns_label} given but the server "
-                f"conditioner {type(self.cfg.conditioner).__name__} carries "
-                f"no label payload — the guidance would be silently dropped")
-        pin = state_pin(self.pcfg, jnp.asarray(obs)[None])
-        label = (self.pcfg.null_label if returns_label is None
-                 else int(returns_label))
-        rows = {"label": jnp.int32(label), **{k: v[0] for k, v in pin.items()}}
-        unknown = set(struct) - set(rows)
-        if unknown:
-            raise ValueError(
-                f"server conditioner declares payload keys {sorted(unknown)} "
-                f"the planner cannot fill (have {sorted(rows)})")
-        return {k: rows[k] for k in struct}
+        conditioners get exactly the keys they declare. Traced as
+        ``plan/request`` on the batcher's tracer (DESIGN.md §15)."""
+        with self.batcher.tracer.span("plan/request"):
+            struct = self.cfg.conditioner.cond_struct(1, self.pcfg.sample_shape)
+            if returns_label is not None and "label" not in struct:
+                raise ValueError(
+                    f"returns_label={returns_label} given but the server "
+                    f"conditioner {type(self.cfg.conditioner).__name__} carries "
+                    f"no label payload — the guidance would be silently dropped")
+            pin = state_pin(self.pcfg, jnp.asarray(obs)[None])
+            label = (self.pcfg.null_label if returns_label is None
+                     else int(returns_label))
+            rows = {"label": jnp.int32(label), **{k: v[0] for k, v in pin.items()}}
+            unknown = set(struct) - set(rows)
+            if unknown:
+                raise ValueError(
+                    f"server conditioner declares payload keys {sorted(unknown)} "
+                    f"the planner cannot fill (have {sorted(rows)})")
+            return {k: rows[k] for k in struct}
 
     def rollout(
         self,

@@ -52,7 +52,6 @@ only watches t and swaps slots.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -71,7 +70,7 @@ from repro.observability.metrics import MetricsRegistry
 from repro.observability.telemetry import (
     StepTelemetry, init_telemetry, telemetry_history,
 )
-from repro.observability.tracing import NULL_TRACER, profiler_annotation
+from repro.observability.tracing import NULL_TRACER
 from repro.serving.scheduler import (
     AdmissionPolicy, FifoAdmission, TierAccounting, tier_name,
 )
@@ -123,7 +122,13 @@ class ImageRequest:
     deadline_at: Optional[float] = dataclasses.field(default=None, repr=False)
     _admit_iters: int = dataclasses.field(default=0, repr=False)
     _submit_t: float = dataclasses.field(default=0.0, repr=False)
-    _seat_t: float = dataclasses.field(default=0.0, repr=False)
+    _seat_t: Optional[float] = dataclasses.field(default=None, repr=False)
+
+    @property
+    def queue_wait_s(self) -> Optional[float]:
+        """Seconds from ``submit()`` to the admission that seated this
+        request, on the batcher's clock; None until it is seated."""
+        return None if self._seat_t is None else self._seat_t - self._submit_t
 
 
 class DiffusionBatcher:
@@ -366,9 +371,11 @@ class DiffusionBatcher:
         here (counted), so transfer accounting — and the regression test
         pinning the device-resident path to O(events) — sees all of
         them. One call = one logical sync, however many leaves ride in
-        the pytree."""
+        the pytree; its ``serve/pull`` span is where the host waits for
+        the device."""
         self._c_transfers.inc()
-        return jax.device_get(tree)
+        with self.tracer.span("serve/pull"):
+            return jax.device_get(tree)
 
     def _h2d_vec(self, arr):
         """Upload a (B,)-ish host array with the carry's vector
@@ -660,12 +667,12 @@ class DiffusionBatcher:
         waste accounting (shared by the host-driven and device-resident
         paths)."""
         now = self._clock()
-        with self.tracer.span(
-            "serve/delivery",
-            uids=[self._slot_req[i].uid for i in conv_idx],
-            slots=list(conv_idx),
-            nfe=[int(nfe[i]) for i in conv_idx],
-        ):
+        attrs = {}
+        if self.tracer.enabled:
+            attrs = dict(uids=[self._slot_req[i].uid for i in conv_idx],
+                         slots=list(conv_idx),
+                         nfe=[int(nfe[i]) for i in conv_idx])
+        with self.tracer.span("serve/delivery", **attrs):
             for row, i in zip(rows, conv_idx):
                 req = self._slot_req[i]
                 req.result = row
@@ -705,10 +712,11 @@ class DiffusionBatcher:
                 req._admit_iters = self.total_iterations
                 req._seat_t = now
                 self.refills_per_device[self.slot_device(i)] += 1
-            # request-id propagation (DESIGN.md §15): the admission span
-            # names exactly the uids seated and the slots they took
-            sp["attrs"]["uids"] = [r.uid for r in reqs]
-            sp["attrs"]["slots"] = list(admit_pos)
+            if self.tracer.enabled:
+                # request-id propagation (DESIGN.md §15): the admission
+                # span names exactly the uids seated and the slots they took
+                sp["attrs"]["uids"] = [r.uid for r in reqs]
+                sp["attrs"]["slots"] = list(admit_pos)
         return admit_pos, reqs
 
     def _compaction_perm(self) -> np.ndarray:
@@ -737,127 +745,130 @@ class DiffusionBatcher:
         admissions are applied device-side (gather + row scatters), so
         the big (B, ...) state never round-trips through the host.
         """
-        c = self._carry
-        # the device's own convergence mask — using anything else (e.g. a
-        # host-side t threshold) can disagree with the loop's active mask
-        # and make retirement depend on the sync horizon
-        done = self._d2h(c.done)
-        occupied = [r is not None for r in self._slot_req]
-        conv = [occupied[i] and bool(done[i]) for i in range(self.n)]
-        if not self.compaction and occupied != conv and any(occupied):
-            # monolithic-wave baseline: the batch only turns over once
-            # every occupied slot has converged
-            return
-        if not any(conv) and not (self.queue and not all(occupied)):
-            return
+        with self.tracer.span("serve/sync"):
+            c = self._carry
+            # the device's own convergence mask — using anything else (e.g. a
+            # host-side t threshold) can disagree with the loop's active mask
+            # and make retirement depend on the sync horizon
+            done = self._d2h(c.done)
+            occupied = [r is not None for r in self._slot_req]
+            conv = [occupied[i] and bool(done[i]) for i in range(self.n)]
+            if not self.compaction and occupied != conv and any(occupied):
+                # monolithic-wave baseline: the batch only turns over once
+                # every occupied slot has converged
+                return
+            if not any(conv) and not (self.queue and not all(occupied)):
+                return
 
-        # 1. deliver converged slots: transfer only those rows. Samples
-        #    are delivered at the t_eps state, pre-Tweedie-denoise — the
-        #    batcher holds only the fused sample_step, not a standalone
-        #    score_fn, so the paper's +1-NFE denoise epilogue is the
-        #    caller's (cf. sample()/finalize(denoise=True))
-        conv_idx = [i for i in range(self.n) if conv[i]]
-        if conv_idx:
-            # delivery is always fp32 regardless of the state dtype
-            rows_j = c.x[jnp.asarray(conv_idx)].astype(jnp.float32)
-            if self.conditioner is not None:
-                # exact, noise-free constraint replacement on delivery
-                # (DESIGN.md §9): e.g. inpainting pins observed pixels
-                # to the observation, matching the finalize() contract
-                cond_rows = jax.tree_util.tree_map(
-                    lambda l: l[jnp.asarray(conv_idx)], c.cond
+            # 1. deliver converged slots: transfer only those rows. Samples
+            #    are delivered at the t_eps state, pre-Tweedie-denoise — the
+            #    batcher holds only the fused sample_step, not a standalone
+            #    score_fn, so the paper's +1-NFE denoise epilogue is the
+            #    caller's (cf. sample()/finalize(denoise=True))
+            conv_idx = [i for i in range(self.n) if conv[i]]
+            if conv_idx:
+                # delivery is always fp32 regardless of the state dtype
+                rows_j = c.x[jnp.asarray(conv_idx)].astype(jnp.float32)
+                if self.conditioner is not None:
+                    # exact, noise-free constraint replacement on delivery
+                    # (DESIGN.md §9): e.g. inpainting pins observed pixels
+                    # to the observation, matching the finalize() contract
+                    cond_rows = jax.tree_util.tree_map(
+                        lambda l: l[jnp.asarray(conv_idx)], c.cond
+                    )
+                    rows_j = self.conditioner.finalize_project(rows_j, cond_rows)
+                rows, nfe, acc, rej = self._d2h(
+                    (rows_j, c.nfe, c.accepted, c.rejected)
                 )
-                rows_j = self.conditioner.finalize_project(rows_j, cond_rows)
-            rows, nfe, acc, rej = self._d2h(
-                (rows_j, c.nfe, c.accepted, c.rejected)
-            )
-            self._retire(rows, nfe, acc, rej, conv_idx)
+                self._retire(rows, nfe, acc, rej, conv_idx)
 
-        # 2. shard-local compaction: each sample's per-slot key moves
-        #    with it, so trajectories are unchanged by the permutation.
-        perm = self._compaction_perm()
-        permute = not np.array_equal(perm, np.arange(self.n))
+            # 2. shard-local compaction: each sample's per-slot key moves
+            #    with it, so trajectories are unchanged by the permutation.
+            perm = self._compaction_perm()
+            permute = not np.array_equal(perm, np.arange(self.n))
 
-        # 3. admit queued requests into freed slots: fresh prior draw at
-        #    t = T under the request's own key — per-slot keys mean the
-        #    admission cannot perturb any in-flight trajectory. The
-        #    request's condition payload (or the neutral one) is written
-        #    into the same rows (DESIGN.md §9).
-        admit_pos, reqs = self._admit_from_queue()
-        priors, noise_keys, conds = [], [], []
-        for req in reqs:
-            k_prior, k_noise = jax.random.split(jax.random.PRNGKey(req.seed))
-            priors.append(self.sde.prior_sample(k_prior, self.shape))
-            noise_keys.append(k_noise)
-            if self.conditioner is not None:
-                conds.append(self._request_cond(req))
+            # 3. admit queued requests into freed slots: fresh prior draw at
+            #    t = T under the request's own key — per-slot keys mean the
+            #    admission cannot perturb any in-flight trajectory. The
+            #    request's condition payload (or the neutral one) is written
+            #    into the same rows (DESIGN.md §9).
+            admit_pos, reqs = self._admit_from_queue()
+            priors, noise_keys, conds = [], [], []
+            with self.tracer.span("serve/keys"):
+                for req in reqs:
+                    k_prior, k_noise = jax.random.split(jax.random.PRNGKey(req.seed))
+                    priors.append(self.sde.prior_sample(k_prior, self.shape))
+                    noise_keys.append(k_noise)
+                    if self.conditioner is not None:
+                        conds.append(self._request_cond(req))
 
-        # a retired-but-unrefilled slot needs no explicit marking: the
-        # device loop already left it at t ≤ t_eps with done=True, which
-        # is exactly the chunk predicate's idle state
-        def update(leaf, admit_val=None):
-            if permute:
-                leaf = jnp.take(leaf, jnp.asarray(perm), axis=0)
-            if admit_pos and admit_val is not None:
-                leaf = leaf.at[jnp.asarray(admit_pos)].set(admit_val)
-            return leaf
+            with self.tracer.span("serve/update"):
+                # a retired-but-unrefilled slot needs no explicit marking: the
+                # device loop already left it at t ≤ t_eps with done=True, which
+                # is exactly the chunk predicate's idle state
+                def update(leaf, admit_val=None):
+                    if permute:
+                        leaf = jnp.take(leaf, jnp.asarray(perm), axis=0)
+                    if admit_pos and admit_val is not None:
+                        leaf = leaf.at[jnp.asarray(admit_pos)].set(admit_val)
+                    return leaf
 
-        x_admit = jnp.stack(priors).astype(c.x.dtype) if admit_pos else None
-        h0 = min(self.cfg.h_init, self.sde.T - self.sde.t_eps)
-        # tiered admission (DESIGN.md §14): each admitted request's
-        # tolerance-class (atol, rtol, h0) rows scatter into the same
-        # positions as its prior/key rows; untiered servers keep the
-        # scalar h0 write below, bit for bit
-        tol_a = tol_r = tol_h = None
-        if self.tiered and admit_pos:
-            tols = [self._request_tol(r) for r in reqs]
-            tol_a = jnp.asarray([t[0] for t in tols], jnp.float32)
-            tol_r = jnp.asarray([t[1] for t in tols], jnp.float32)
-            tol_h = jnp.asarray([t[2] for t in tols], jnp.float32)
-        # condition leaves move with their samples (permute + row scatter
-        # like every other per-slot leaf — the DESIGN.md §9 compaction
-        # rule: payloads travel shard-locally, like keys)
-        cond_new = c.cond
-        if c.cond is not None:
-            if admit_pos:
-                cond_admit = jax.tree_util.tree_map(
-                    lambda *rows: jnp.stack(rows), conds[0], *conds[1:]
-                )
-                cond_new = jax.tree_util.tree_map(
-                    lambda leaf, av: update(leaf, admit_val=av.astype(leaf.dtype)),
-                    c.cond, cond_admit,
-                )
-            else:
-                cond_new = jax.tree_util.tree_map(update, c.cond)
-        self._carry = self._shard_carry(SolverCarry(
-            x=update(c.x, admit_val=x_admit),
-            x_prev=update(c.x_prev, admit_val=x_admit),
-            t=update(c.t, admit_val=jnp.float32(self.sde.T)),
-            h=update(c.h,
-                     admit_val=jnp.float32(h0) if tol_h is None else tol_h),
-            key=update(c.key,
-                       admit_val=jnp.stack(noise_keys) if admit_pos else None),
-            nfe=update(c.nfe, admit_val=jnp.int32(0)),
-            accepted=update(c.accepted, admit_val=jnp.int32(0)),
-            rejected=update(c.rejected, admit_val=jnp.int32(0)),
-            done=update(c.done, admit_val=False),
-            # the carry's iteration counter is per-chunk in serving: fold
-            # it into the host total and reset so cfg.max_iters never
-            # trips on a long-lived server
-            iterations=jnp.asarray(0, jnp.int32),
-            cond=cond_new,
-            atol=(update(c.atol, admit_val=tol_a) if self.tiered else None),
-            rtol=(update(c.rtol, admit_val=tol_r) if self.tiered else None),
-            # telemetry rows permute with their sample and are never
-            # cleared at admission (DESIGN.md §15) — see event_update
-            telemetry=(None if c.telemetry is None else StepTelemetry(
-                t=update(c.telemetry.t), h=update(c.telemetry.h),
-                err=update(c.telemetry.err),
-                accept=update(c.telemetry.accept),
-                head=c.telemetry.head,
-            )),
-        ))
-        self._host_iters = 0
+                x_admit = jnp.stack(priors).astype(c.x.dtype) if admit_pos else None
+                h0 = min(self.cfg.h_init, self.sde.T - self.sde.t_eps)
+                # tiered admission (DESIGN.md §14): each admitted request's
+                # tolerance-class (atol, rtol, h0) rows scatter into the same
+                # positions as its prior/key rows; untiered servers keep the
+                # scalar h0 write below, bit for bit
+                tol_a = tol_r = tol_h = None
+                if self.tiered and admit_pos:
+                    tols = [self._request_tol(r) for r in reqs]
+                    tol_a = jnp.asarray([t[0] for t in tols], jnp.float32)
+                    tol_r = jnp.asarray([t[1] for t in tols], jnp.float32)
+                    tol_h = jnp.asarray([t[2] for t in tols], jnp.float32)
+                # condition leaves move with their samples (permute + row scatter
+                # like every other per-slot leaf — the DESIGN.md §9 compaction
+                # rule: payloads travel shard-locally, like keys)
+                cond_new = c.cond
+                if c.cond is not None:
+                    if admit_pos:
+                        cond_admit = jax.tree_util.tree_map(
+                            lambda *rows: jnp.stack(rows), conds[0], *conds[1:]
+                        )
+                        cond_new = jax.tree_util.tree_map(
+                            lambda leaf, av: update(leaf, admit_val=av.astype(leaf.dtype)),
+                            c.cond, cond_admit,
+                        )
+                    else:
+                        cond_new = jax.tree_util.tree_map(update, c.cond)
+                self._carry = self._shard_carry(SolverCarry(
+                    x=update(c.x, admit_val=x_admit),
+                    x_prev=update(c.x_prev, admit_val=x_admit),
+                    t=update(c.t, admit_val=jnp.float32(self.sde.T)),
+                    h=update(c.h,
+                             admit_val=jnp.float32(h0) if tol_h is None else tol_h),
+                    key=update(c.key,
+                               admit_val=jnp.stack(noise_keys) if admit_pos else None),
+                    nfe=update(c.nfe, admit_val=jnp.int32(0)),
+                    accepted=update(c.accepted, admit_val=jnp.int32(0)),
+                    rejected=update(c.rejected, admit_val=jnp.int32(0)),
+                    done=update(c.done, admit_val=False),
+                    # the carry's iteration counter is per-chunk in serving: fold
+                    # it into the host total and reset so cfg.max_iters never
+                    # trips on a long-lived server
+                    iterations=jnp.asarray(0, jnp.int32),
+                    cond=cond_new,
+                    atol=(update(c.atol, admit_val=tol_a) if self.tiered else None),
+                    rtol=(update(c.rtol, admit_val=tol_r) if self.tiered else None),
+                    # telemetry rows permute with their sample and are never
+                    # cleared at admission (DESIGN.md §15) — see event_update
+                    telemetry=(None if c.telemetry is None else StepTelemetry(
+                        t=update(c.telemetry.t), h=update(c.telemetry.h),
+                        err=update(c.telemetry.err),
+                        accept=update(c.telemetry.accept),
+                        head=c.telemetry.head,
+                    )),
+                ))
+            self._host_iters = 0
 
     # ------------------------------------------------------------------
     def _process_events(self, deliver: bool = True) -> None:
@@ -872,91 +883,95 @@ class DiffusionBatcher:
         one bookkeeping pull, plus one retired-rows pull when something
         converged — O(events), never O(horizons).
         """
-        c = self._carry
-        if deliver:
-            done, nfe, acc, rej, iters = self._d2h(
-                (c.done, c.nfe, c.accepted, c.rejected, c.iterations)
-            )
-        else:
-            iters = self._d2h(c.iterations)
-            done = np.zeros(self.n, bool)
-            acc = rej = None
-        # fold-and-reset (cf. event_update): the device counter restarts
-        # at every host visit, so add it exactly once here
-        self._c_iters.inc(int(iters))
-        self._host_iters = 0
-        occupied = [r is not None for r in self._slot_req]
-        conv_idx = [i for i in range(self.n) if occupied[i] and bool(done[i])]
-        if conv_idx:
-            rows_j = c.x[jnp.asarray(conv_idx)].astype(jnp.float32)
-            if self.conditioner is not None:
-                cond_rows = jax.tree_util.tree_map(
-                    lambda l: l[jnp.asarray(conv_idx)], c.cond
+        with self.tracer.span("serve/event", deliver=deliver):
+            c = self._carry
+            if deliver:
+                done, nfe, acc, rej, iters = self._d2h(
+                    (c.done, c.nfe, c.accepted, c.rejected, c.iterations)
                 )
-                rows_j = self.conditioner.finalize_project(rows_j, cond_rows)
-            self._retire(self._d2h(rows_j), nfe, acc, rej, conv_idx)
+            else:
+                iters = self._d2h(c.iterations)
+                done = np.zeros(self.n, bool)
+                acc = rej = None
+            # fold-and-reset (cf. event_update): the device counter restarts
+            # at every host visit, so add it exactly once here
+            self._c_iters.inc(int(iters))
+            self._host_iters = 0
+            occupied = [r is not None for r in self._slot_req]
+            conv_idx = [i for i in range(self.n) if occupied[i] and bool(done[i])]
+            if conv_idx:
+                rows_j = c.x[jnp.asarray(conv_idx)].astype(jnp.float32)
+                if self.conditioner is not None:
+                    cond_rows = jax.tree_util.tree_map(
+                        lambda l: l[jnp.asarray(conv_idx)], c.cond
+                    )
+                    rows_j = self.conditioner.finalize_project(rows_j, cond_rows)
+                self._retire(self._d2h(rows_j), nfe, acc, rej, conv_idx)
 
-        perm = self._compaction_perm()
-        permute = not np.array_equal(perm, np.arange(self.n))
-        can_admit = self.compaction or not any(
-            r is not None for r in self._slot_req
-        )
-        admit_pos, reqs = self._admit_from_queue() if can_admit else ([], [])
-        if permute or admit_pos:
-            admit_mask = np.zeros(self.n, bool)
-            admit_mask[admit_pos] = True
-            keys = [jax.random.split(jax.random.PRNGKey(r.seed)) for r in reqs]
-            kbuf = lambda rows: (
-                jnp.zeros((self.n, 2), jnp.uint32)
-                .at[jnp.asarray(admit_pos, jnp.int32)]
-                .set(jnp.stack(rows)) if admit_pos
-                else jnp.zeros((self.n, 2), jnp.uint32)
+            perm = self._compaction_perm()
+            permute = not np.array_equal(perm, np.arange(self.n))
+            can_admit = self.compaction or not any(
+                r is not None for r in self._slot_req
             )
-            ops = [
-                self._carry,
-                self._h2d_vec(perm.astype(np.int32)),
-                self._h2d_vec(admit_mask),
-                kbuf([k[0] for k in keys]),  # prior keys → on-device draws
-                kbuf([k[1] for k in keys]),  # per-slot noise streams
-            ]
-            if self.tiered:
-                # per-request tolerance rows ride the same fixed-shape
-                # full-B buffer pattern as the key rows (DESIGN.md §14)
-                tols = [self._request_tol(r) for r in reqs]
+            admit_pos, reqs = self._admit_from_queue() if can_admit else ([], [])
+            with self.tracer.span("serve/keys"):
+                keys = [jax.random.split(jax.random.PRNGKey(r.seed))
+                        for r in reqs]
+            with self.tracer.span("serve/update"):
+                if permute or admit_pos:
+                    admit_mask = np.zeros(self.n, bool)
+                    admit_mask[admit_pos] = True
+                    kbuf = lambda rows: (
+                        jnp.zeros((self.n, 2), jnp.uint32)
+                        .at[jnp.asarray(admit_pos, jnp.int32)]
+                        .set(jnp.stack(rows)) if admit_pos
+                        else jnp.zeros((self.n, 2), jnp.uint32)
+                    )
+                    ops = [
+                        self._carry,
+                        self._h2d_vec(perm.astype(np.int32)),
+                        self._h2d_vec(admit_mask),
+                        kbuf([k[0] for k in keys]),  # prior keys → on-device draws
+                        kbuf([k[1] for k in keys]),  # per-slot noise streams
+                    ]
+                    if self.tiered:
+                        # per-request tolerance rows ride the same fixed-shape
+                        # full-B buffer pattern as the key rows (DESIGN.md §14)
+                        tols = [self._request_tol(r) for r in reqs]
 
-                def fbuf(vals):
-                    buf = np.zeros(self.n, np.float32)
-                    if admit_pos:
-                        buf[admit_pos] = vals
-                    return self._h2d_vec(buf)
+                        def fbuf(vals):
+                            buf = np.zeros(self.n, np.float32)
+                            if admit_pos:
+                                buf[admit_pos] = vals
+                            return self._h2d_vec(buf)
 
-                ops += [fbuf([t[0] for t in tols]),
-                        fbuf([t[1] for t in tols]),
-                        fbuf([t[2] for t in tols])]
-            self._carry = self._event_fn(*ops)
-            if self.conditioner is not None and admit_pos:
-                # admission payloads stay per-request: the ragged cond
-                # rows are scattered outside the fixed-shape event jit
-                # (DESIGN.md §12)
-                rows = [self._request_cond(r) for r in reqs]
-                cond_admit = jax.tree_util.tree_map(
-                    lambda *ls: jnp.stack(ls), rows[0], *rows[1:]
-                )
-                idx = jnp.asarray(admit_pos, jnp.int32)
-                self._carry = dataclasses.replace(
-                    self._carry,
-                    cond=jax.tree_util.tree_map(
-                        lambda leaf, av: leaf.at[idx].set(av.astype(leaf.dtype)),
-                        self._carry.cond, cond_admit,
-                    ),
-                )
-        elif int(iters):
-            # nothing moved, but the pulled counter was folded above —
-            # restart the device counter so it is never double-counted
-            self._carry = dataclasses.replace(
-                c, iterations=jnp.asarray(0, jnp.int32)
-            )
-        self._set_occupied()
+                        ops += [fbuf([t[0] for t in tols]),
+                                fbuf([t[1] for t in tols]),
+                                fbuf([t[2] for t in tols])]
+                    self._carry = self._event_fn(*ops)
+                    if self.conditioner is not None and admit_pos:
+                        # admission payloads stay per-request: the ragged cond
+                        # rows are scattered outside the fixed-shape event jit
+                        # (DESIGN.md §12)
+                        rows = [self._request_cond(r) for r in reqs]
+                        cond_admit = jax.tree_util.tree_map(
+                            lambda *ls: jnp.stack(ls), rows[0], *rows[1:]
+                        )
+                        idx = jnp.asarray(admit_pos, jnp.int32)
+                        self._carry = dataclasses.replace(
+                            self._carry,
+                            cond=jax.tree_util.tree_map(
+                                lambda leaf, av: leaf.at[idx].set(av.astype(leaf.dtype)),
+                                self._carry.cond, cond_admit,
+                            ),
+                        )
+                elif int(iters):
+                    # nothing moved, but the pulled counter was folded above —
+                    # restart the device counter so it is never double-counted
+                    self._carry = dataclasses.replace(
+                        c, iterations=jnp.asarray(0, jnp.int32)
+                    )
+                self._set_occupied()
 
     def _device_step(self) -> int:
         """One device-resident window: ≤ max_horizons · sync_horizon
@@ -971,11 +986,9 @@ class DiffusionBatcher:
         busy = sum(1 for r in self._slot_req if r is not None)
         if busy == 0:
             return 0
-        ann = (profiler_annotation("serve/solve", step=self.horizon_windows)
-               if self.tracer.enabled else contextlib.nullcontext())
         with self.tracer.span(
             "serve/solve", window=self.horizon_windows, busy=busy
-        ), ann:
+        ):
             self._carry, ev = self._driver_fn(
                 self.params, self._carry, self._occupied
             )
@@ -996,11 +1009,9 @@ class DiffusionBatcher:
         busy = sum(1 for r in self._slot_req if r is not None)
         if busy == 0:
             return 0
-        ann = (profiler_annotation("serve/solve", step=self.horizon_windows)
-               if self.tracer.enabled else contextlib.nullcontext())
         with self.tracer.span(
             "serve/solve", window=self.horizon_windows, busy=busy
-        ), ann:
+        ):
             self._carry = self.step_fn(self.params, self._carry)
             cur = int(self._d2h(self._carry.iterations))
         self.horizon_windows += 1

@@ -52,7 +52,8 @@ Array = jax.Array
 # device program (sample_step / driver), these classes are the pluggable
 # host-side halves. They are duck-typed over request objects exposing
 # ``priority`` (int band, lower = more urgent), ``deadline_at`` (absolute
-# clock time or None), ``_submit_t`` (submission clock time) and ``uid``
+# clock time or None), ``_submit_t`` (submission clock time), ``uid`` and,
+# once seated, ``queue_wait_s`` (seconds from submission to seating)
 # — both ``ImageRequest`` and any future request type qualify.
 # ---------------------------------------------------------------------------
 
@@ -180,7 +181,7 @@ class TierAccounting:
         s = self.stats.setdefault(name, TierStats())
         s.delivered += 1
         s.nfe_total += int(req.nfe)
-        wait = max(0.0, req._seat_t - req._submit_t)
+        wait = req.queue_wait_s
         s.wait_s_total += wait
         missed = req.deadline_at is not None and now > req.deadline_at
         req.deadline_missed = missed

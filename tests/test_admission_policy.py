@@ -87,7 +87,7 @@ class Req:
     priority: int = 0
     deadline_at: Optional[float] = None
     _submit_t: float = 0.0
-    _seat_t: float = 0.0
+    queue_wait_s: float = 0.0
     tier: Optional[str] = None
     nfe: int = 0
     deadline_missed: bool = False

@@ -41,7 +41,7 @@ from repro.analysis.telemetry import (
     active_records, nfe_percentiles, step_size_vs_t, telemetry_markdown,
 )
 from repro.core import AdaptiveConfig, VPSDE
-from repro.core.analytic import gaussian_noise_pred
+from repro.core.analytic import class_gaussian_noise_pred, gaussian_noise_pred
 from repro.core.solvers.adaptive import init_carry, solve_chunk
 from repro.launch.sample import make_sample_step
 from repro.models.dit import DiTConfig
@@ -49,8 +49,10 @@ from repro.observability import (
     NULL_TRACER, MetricsRegistry, StageTracer, dynamics_consistency,
     proxy_fid, telemetry_history,
 )
+from repro.planning import PlannerConfig, RecedingHorizonPlanner
 from repro.planning.envs import OUEnv, PointMassEnv
 from repro.serving.diffusion_server import DiffusionBatcher, ImageRequest
+from repro.serving.scheduler import tier_name
 
 MU, S0 = 0.3, 0.5
 D = 32
@@ -424,6 +426,150 @@ def test_no_retrace_with_telemetry_on(families):
                    telemetry=128)
     assert bd._driver_fn._cache_size() == 1
     assert bd._event_fn._cache_size() == 1
+
+
+# --------------------------------------------------------------------------
+# serve-loop spans on the profiler's clock: every host visit between
+# device programs, nested as the records say
+# --------------------------------------------------------------------------
+
+#: the spans each serve path must put into the profiler's host plane
+PATH_SPANS = {
+    "device-resident": {"serve/event", "serve/pull", "serve/keys",
+                        "serve/update", "serve/solve", "serve/admission",
+                        "serve/delivery"},
+    "host-driven": {"serve/sync", "serve/pull", "serve/keys",
+                    "serve/update", "serve/solve", "serve/admission",
+                    "serve/delivery", "plan/request"},
+}
+SPAN_NAMES = set().union(*PATH_SPANS.values()) | {"plan/round"}
+
+
+def _run_path(families, path, tracer):
+    """One tiny drain of ``path`` with more requests than slots: the
+    tiered device-resident server, or the receding-horizon planner over
+    its host-driven batcher. Returns the batcher."""
+    sde, fam = families
+    cfg, step = fam["adaptive"]
+    if path == "device-resident":
+        b, _ = _serve(sde, cfg, step, n_req=len(WAVE), tiers=WAVE,
+                      sync_horizon=4, device_resident=True,
+                      tolerance_classes=True, tracer=tracer)
+        return b
+    rh = RecedingHorizonPlanner(
+        sde, class_gaussian_noise_pred(sde, jnp.linspace(-1.0, 1.0, 5)),
+        None, PlannerConfig(horizon=8, obs_dim=2, act_dim=2,
+                            guidance_scale=1.5),
+        OUEnv(obs_dim=2), slots=4, sync_horizon=4, tracer=tracer)
+    rh.rollout(jax.random.PRNGKey(1), n_envs=6, n_steps=2, returns_label=1)
+    return rh.batcher
+
+
+def _profiled_run(families, path, tracer, log_dir):
+    """Run ``path`` under ``jax.profiler``; returns the batcher and the
+    host-plane events named like a serve-loop span, as (start_ns,
+    end_ns, name)."""
+    with jax.profiler.trace(str(log_dir)):
+        b = _run_path(families, path, tracer)
+    (xplane,) = log_dir.rglob("*.xplane.pb")
+    host = []
+    for plane in jax.profiler.ProfileData.from_file(str(xplane)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                host += [(int(ev.start_ns),
+                          int(ev.start_ns + ev.duration_ns), ev.name)
+                         for ev in line.events if ev.name in SPAN_NAMES]
+    return b, host
+
+
+@pytest.fixture(scope="module", params=list(PATH_SPANS))
+def profiled(request, families, tmp_path_factory):
+    """A traced drain of each serve path under the profiler: (path,
+    batcher, tracer, host-plane span events)."""
+    tracer = StageTracer()
+    b, host = _profiled_run(families, request.param, tracer,
+                            tmp_path_factory.mktemp("profile"))
+    return request.param, b, tracer, host
+
+
+def test_serve_spans_nest_on_the_profiler_clock(profiled):
+    """Each host visit of the path is a span in the profiler's host
+    plane under its bare name (no attrs in the name), one trace event
+    per record; the records name their enclosing span, and the trace
+    nests exactly as the records say: each child inside its parent."""
+    path, _, tracer, host = profiled
+    assert PATH_SPANS[path] <= {name for _, _, name in host}
+    recs = sorted(tracer.spans, key=lambda r: r["id"])  # opening order
+    assert [r["id"] for r in recs] == list(range(len(recs)))
+    events = sorted(host, key=lambda ev: (ev[0], -ev[1]))  # opening order
+    assert [ev[2] for ev in events] == [r["name"] for r in recs]
+    stack = []
+    for rec, (start, end, _) in zip(recs, events):
+        while stack and events[stack[-1]][1] <= start:
+            stack.pop()
+        assert rec["parent"] == (stack[-1] if stack else None), rec
+        if stack:
+            p_start, p_end, _ = events[stack[-1]]
+            assert p_start <= start and end <= p_end, rec
+            parent = recs[rec["parent"]]
+            assert parent["start"] <= rec["start"] <= rec["end"] \
+                <= parent["end"], rec
+        stack.append(rec["id"])
+    # the device→host reads the batcher counted are the pulls it traced
+    assert sum(r["name"] == "serve/pull" for r in recs) \
+        == profiled[1].host_transfers
+
+
+def test_traced_serve_is_bitwise_identical_to_untraced(profiled, families,
+                                                       tmp_path):
+    """Spans observe and change nothing: the untraced drain of the same
+    path delivers bit-identical samples with the same NFE and reads, and
+    the null tracer puts no annotation into a running profiler."""
+    path, traced, _, _ = profiled
+    plain, host = _profiled_run(families, path, NULL_TRACER, tmp_path)
+    assert host == [] and NULL_TRACER.spans == []
+    assert list(plain.finished) == list(traced.finished)
+    for uid, req in traced.finished.items():
+        np.testing.assert_array_equal(req.result, plain.finished[uid].result)
+        assert req.nfe == plain.finished[uid].nfe
+    assert plain.host_transfers == traced.host_transfers
+    assert plain.total_iterations == traced.total_iterations
+
+
+def test_queue_wait_is_a_public_reading_behind_the_histogram(profiled):
+    """``queue_wait_s`` is None until a request is seated, then the
+    seconds from submit to seat (>= 0, > 0 for the requests that queued
+    behind full slots); the delivery stage's per-tier
+    ``serve_queue_wait_seconds`` histogram sums exactly those readings."""
+    assert ImageRequest(uid=0, seed=0).queue_wait_s is None
+    _, b, _, _ = profiled
+    waits = {}
+    for req in b.finished.values():
+        assert req.queue_wait_s is not None and req.queue_wait_s >= 0.0
+        waits.setdefault(tier_name(req), []).append(req.queue_wait_s)
+    assert max(max(w) for w in waits.values()) > 0.0
+    for tier, w in waits.items():
+        h = b.metrics.histogram("serve_queue_wait_seconds", tier=tier)
+        assert h.count == len(w) and h.total == sum(w), tier
+
+
+def test_stage_tracer_records_parent_ids():
+    """Span ids count up in opening order; ``parent`` is the enclosing
+    open span, None at top level, and a span that raised closes
+    cleanly."""
+    tr = StageTracer()
+    with tr.span("a"):
+        with tr.span("b"):
+            pass
+        with pytest.raises(ValueError):
+            with tr.span("c"):
+                raise ValueError
+    with tr.span("d"):
+        pass
+    by = {r["name"]: r for r in tr.spans}
+    assert [by[n]["id"] for n in "abcd"] == [0, 1, 2, 3]
+    assert [by[n]["parent"] for n in "abcd"] == [None, 0, 0, None]
+    assert json.loads(json.dumps(tr.to_json()))["spans"] == tr.spans
 
 
 # --------------------------------------------------------------------------
