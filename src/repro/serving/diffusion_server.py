@@ -40,10 +40,11 @@ delivery — fires, and the host reads back exactly one scalar
 the host pull the (B,) bookkeeping + retired rows, compute the
 compaction permutation and admissions, and apply them through a second
 jitted, donated event update (gather by permutation, masked admission
-scatter, on-device prior draws from per-request keys). Host↔device
-traffic is O(delivered requests), not O(sync horizons); delivered
-samples are bit-identical to the host-driven loop because per-slot keys
-make trajectories invariant to slot placement and sync timing.
+scatter, per-request keys derived from seed words and prior draws, all
+on device). Host↔device traffic is O(delivered requests), not O(sync
+horizons); delivered samples are bit-identical to the host-driven loop
+because per-slot keys make trajectories invariant to slot placement and
+sync timing.
 
 Device step = repro.launch.sample.make_sample_step (the same
 ``solve_chunk`` unit the production-mesh dry-run lowers); the host loop
@@ -268,6 +269,8 @@ class DiffusionBatcher:
         self._c_useful = self.metrics.counter("serve_nfe_useful_total")
         self._c_resident = self.metrics.counter("serve_nfe_resident_total")
         self._c_transfers = self.metrics.counter("serve_host_transfers_total")
+        self._c_uploads = self.metrics.counter("serve_host_uploads_total")
+        self._c_events = self.metrics.counter("serve_event_updates_total")
         self._c_accept = self.metrics.counter("serve_accepted_total")
         self._c_reject = self.metrics.counter("serve_rejected_total")
         if hasattr(self.delivery, "bind"):
@@ -377,19 +380,23 @@ class DiffusionBatcher:
         with self.tracer.span("serve/pull"):
             return jax.device_get(tree)
 
-    def _h2d_vec(self, arr):
-        """Upload a (B,)-ish host array with the carry's vector
-        sharding (no-op placement without a mesh)."""
-        arr = jnp.asarray(arr)
-        if self._carry_shardings is not None:
-            arr = jax.device_put(arr, self._carry_shardings.done)
-        return arr
+    def _h2d(self, arr: np.ndarray):
+        """The device-resident serve loop's single host→device seam, the
+        mirror of ``_d2h``: one numpy array, slot axis first, placed in
+        one ``device_put`` with the carry's slot sharding (the default
+        device without a mesh) and counted in
+        ``serve_host_uploads_total``."""
+        self._c_uploads.inc()
+        return jax.device_put(
+            arr, None if self._carry_shardings is None
+            else self._carry_shardings.done
+        )
 
     def _set_occupied(self) -> None:
         """Mirror host slot occupancy into the device-side (B,) mask the
         driver's ``events_pending`` consults (idle slots ride with
         done=True, so the device cannot derive occupancy from the carry)."""
-        self._occupied = self._h2d_vec(
+        self._occupied = self._h2d(
             np.array([r is not None for r in self._slot_req])
         )
 
@@ -402,14 +409,15 @@ class DiffusionBatcher:
         carry plus the scalar event flag — the sole per-call read. The
         *event update* applies one host decision batch entirely
         on-device: gather every carry leaf by the compaction
-        permutation, then overwrite admitted rows with fresh prior draws
-        (vmapped over the admitted requests' own prior keys — bit-
-        identical to the host's per-key draws), reset their control
-        fields, and install their noise keys. Both donate the carry, so
-        the (B, ...) state buffers are reused in place rather than
-        copied per call. The admission inputs are fixed-shape full-B
-        buffers (mask + key rows) to keep a single trace; only the
-        *condition payload* rows are scattered host-side afterwards —
+        permutation, then overwrite admitted rows with fresh prior draws,
+        reset their control fields, and install their noise keys. Each
+        admitted request's keys are derived in the program from its seed
+        word, ``split(PRNGKey(seed))`` vmapped over the slots — bit-
+        identical to the host-driven loop's eager split. Both donate the
+        carry, so the (B, ...) state buffers are reused in place rather
+        than copied per call. The admission inputs are fixed-shape full-B
+        host arrays (one, two when tiered) to keep a single trace; only
+        the *condition payload* rows are scattered host-side afterwards —
         admission payloads stay per-request (ragged pytrees, not worth a
         trace per admission-count), see DESIGN.md §12.
         """
@@ -436,28 +444,33 @@ class DiffusionBatcher:
             )
             return carry, events_pending(carry, occupied, wait_all=wait_all)
 
-        def event_update(carry, perm, admit_mask, prior_keys, noise_keys,
-                         admit_atol=None, admit_rtol=None, admit_h=None):
-            # the three trailing (B,) fp32 buffers are the tiered
-            # admission's per-request tolerance/step rows (DESIGN.md
-            # §14); the untiered server never passes them, so its trace
-            # and donation layout are unchanged
-            def upd(leaf, admit):
+        def event_update(carry, admit, tol=None):
+            # admit (B, 3) uint32, per slot: [compaction source slot,
+            # admitted?, seed word]; tol (B, 3) fp32 [atol, rtol, h0] is
+            # the tiered admission's rows (DESIGN.md §14) — the untiered
+            # server never passes it, so its trace is unchanged
+            perm = admit[:, 0].astype(jnp.int32)
+            admit_mask = admit[:, 1] != 0
+
+            def upd(leaf, new):
                 leaf = jnp.take(leaf, perm, axis=0)
                 m = admit_mask.reshape((-1,) + (1,) * (leaf.ndim - 1))
-                return jnp.where(m, admit, leaf)
+                return jnp.where(m, new, leaf)
 
+            # row 0 draws the prior, row 1 is the slot's noise stream
+            keys = jax.vmap(
+                lambda s: jax.random.split(jax.random.PRNGKey(s))
+            )(admit[:, 2])
             priors = jax.vmap(
                 lambda k: self.sde.prior_sample(k, self.shape)
-            )(prior_keys).astype(carry.x.dtype)
+            )(keys[:, 0]).astype(carry.x.dtype)
             h0 = min(self.cfg.h_init, self.sde.T - self.sde.t_eps)
             return SolverCarry(
                 x=upd(carry.x, priors),
                 x_prev=upd(carry.x_prev, priors),
                 t=upd(carry.t, jnp.float32(self.sde.T)),
-                h=upd(carry.h,
-                      jnp.float32(h0) if admit_h is None else admit_h),
-                key=upd(carry.key, noise_keys),
+                h=upd(carry.h, jnp.float32(h0) if tol is None else tol[:, 2]),
+                key=upd(carry.key, keys[:, 1]),
                 nfe=upd(carry.nfe, 0),
                 accepted=upd(carry.accepted, 0),
                 rejected=upd(carry.rejected, 0),
@@ -471,10 +484,8 @@ class DiffusionBatcher:
                       jax.tree_util.tree_map(
                           lambda l: jnp.take(l, perm, axis=0), carry.cond
                       )),
-                atol=(None if carry.atol is None
-                      else upd(carry.atol, admit_atol)),
-                rtol=(None if carry.rtol is None
-                      else upd(carry.rtol, admit_rtol)),
+                atol=(None if carry.atol is None else upd(carry.atol, tol[:, 0])),
+                rtol=(None if carry.rtol is None else upd(carry.rtol, tol[:, 1])),
                 # telemetry rows travel with their sample, permute-only
                 # (DESIGN.md §15): admission does NOT clear rows —
                 # records are globally iteration-stamped and age out by
@@ -914,40 +925,23 @@ class DiffusionBatcher:
                 r is not None for r in self._slot_req
             )
             admit_pos, reqs = self._admit_from_queue() if can_admit else ([], [])
+            # the admission reaches the device as full-B host arrays; the
+            # event program derives each request's keys from its seed word
+            # (the low 32 bits, all an x64-off PRNGKey keeps)
+            admit = np.zeros((self.n, 3), np.uint32)
             with self.tracer.span("serve/keys"):
-                keys = [jax.random.split(jax.random.PRNGKey(r.seed))
-                        for r in reqs]
+                for i, r in zip(admit_pos, reqs):
+                    admit[i, 1:] = 1, r.seed & 0xFFFFFFFF
             with self.tracer.span("serve/update"):
                 if permute or admit_pos:
-                    admit_mask = np.zeros(self.n, bool)
-                    admit_mask[admit_pos] = True
-                    kbuf = lambda rows: (
-                        jnp.zeros((self.n, 2), jnp.uint32)
-                        .at[jnp.asarray(admit_pos, jnp.int32)]
-                        .set(jnp.stack(rows)) if admit_pos
-                        else jnp.zeros((self.n, 2), jnp.uint32)
-                    )
-                    ops = [
-                        self._carry,
-                        self._h2d_vec(perm.astype(np.int32)),
-                        self._h2d_vec(admit_mask),
-                        kbuf([k[0] for k in keys]),  # prior keys → on-device draws
-                        kbuf([k[1] for k in keys]),  # per-slot noise streams
-                    ]
+                    admit[:, 0] = perm
+                    ops = [self._carry, self._h2d(admit)]
                     if self.tiered:
-                        # per-request tolerance rows ride the same fixed-shape
-                        # full-B buffer pattern as the key rows (DESIGN.md §14)
-                        tols = [self._request_tol(r) for r in reqs]
-
-                        def fbuf(vals):
-                            buf = np.zeros(self.n, np.float32)
-                            if admit_pos:
-                                buf[admit_pos] = vals
-                            return self._h2d_vec(buf)
-
-                        ops += [fbuf([t[0] for t in tols]),
-                                fbuf([t[1] for t in tols]),
-                                fbuf([t[2] for t in tols])]
+                        tol = np.zeros((self.n, 3), np.float32)
+                        for i, r in zip(admit_pos, reqs):
+                            tol[i] = self._request_tol(r)
+                        ops.append(self._h2d(tol))
+                    self._c_events.inc()
                     self._carry = self._event_fn(*ops)
                     if self.conditioner is not None and admit_pos:
                         # admission payloads stay per-request: the ragged cond

@@ -16,8 +16,20 @@ Three properties pin it:
     the host-driven loop at sync_horizon ≤ 8, and near-constant as the
     horizon shrinks while the host-driven count blows up;
   * **donation** — the driver actually consumes its input carry, so the
-    hot loop is not double-buffering state.
+    hot loop is not double-buffering state;
+  * **fixed-size admission** — an event visit reaches the device through
+    a fixed number of host uploads (counted by a shim around
+    ``jax.device_put``) and runs no eager op on host values, however
+    many requests it seats: the event program derives every admitted
+    request's keys from its seed word, bit-identical to the eager
+    ``split(PRNGKey)``.
 """
+
+import json
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import numpy as np
@@ -50,20 +62,33 @@ def server_parts():
     return sde, cfg, _make_step(sde, cfg)
 
 
-def _drain(b, n_req, cond_for=None):
+#: seeds whose eager ``PRNGKey`` goes past int32: negative, ≥ 2³¹, ≥ 2³²
+WIDE_SEEDS = (0, 7, 2**31 - 1, 2**31 + 5, 2**32 - 1, -3, 2**40 + 7)
+TIERS = ("draft", "standard", "high_fidelity")
+
+
+def _drain(b, n_req, cond_for=None, seed_for=None, tier_for=None):
     for uid in range(n_req):
-        b.submit(ImageRequest(uid=uid, seed=uid,
-                              cond=cond_for(uid) if cond_for else None))
+        b.submit(ImageRequest(uid=uid,
+                              seed=seed_for(uid) if seed_for else uid,
+                              cond=cond_for(uid) if cond_for else None,
+                              tier=tier_for(uid) if tier_for else None))
     done = b.run_to_completion()
     assert len(done) == n_req
     return done
 
 
-def _run(sde, cfg, step, *, n_req=N_REQ, cond_for=None, **kw):
+def _run(sde, cfg, step, *, n_req=N_REQ, cond_for=None, seed_for=None,
+         tier_for=None, **kw):
     b = DiffusionBatcher(sde, step, params=None, sample_shape=(D,),
                          slots=SLOTS, cfg=cfg, **kw)
-    done = _drain(b, n_req, cond_for)
+    done = _drain(b, n_req, cond_for, seed_for, tier_for)
     return b, np.stack([done[u].result for u in range(n_req)]), done
+
+
+def _wide_seed(uid):
+    """A seed past int32 for most requests, each with its own low word."""
+    return WIDE_SEEDS[uid % len(WIDE_SEEDS)] + (uid // len(WIDE_SEEDS)) * 101
 
 
 # ---------------------------------------------------------------------------
@@ -71,17 +96,31 @@ def _run(sde, cfg, step, *, n_req=N_REQ, cond_for=None, **kw):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("compaction", [True, False],
-                         ids=["compaction", "monolithic"])
+@pytest.mark.parametrize("compaction,wide,tiered", [
+    pytest.param(True, False, False, id="compaction"),
+    pytest.param(False, False, False, id="monolithic"),
+    pytest.param(True, True, False, id="compaction-wide-seeds"),
+    pytest.param(False, True, False, id="monolithic-wide-seeds"),
+    pytest.param(True, True, True, id="compaction-wide-seeds-tiered"),
+    pytest.param(False, True, True, id="monolithic-wide-seeds-tiered"),
+])
 def test_device_resident_bitwise_matches_host_driven(server_parts,
-                                                     compaction):
+                                                     compaction, wide,
+                                                     tiered):
     """Same keys + same request wave ⇒ the device-resident loop delivers
     bit-identical samples AND identical accounting (iterations, per-
     request NFE, waste fraction) to the host-driven ``_sync`` loop —
     retirement/compaction/admission decisions moved devices, the math
-    did not. Holds for both turnover disciplines."""
+    did not. Holds for both turnover disciplines, for seeds past int32
+    (the event program derives keys from seed words, the host-driven
+    loop splits eagerly), and for mixed tolerance tiers."""
     sde, cfg, step = server_parts
     kw = dict(sync_horizon=4, compaction=compaction)
+    if wide:
+        kw["seed_for"] = _wide_seed
+    if tiered:
+        kw.update(tolerance_classes=True,
+                  tier_for=lambda uid: TIERS[uid % len(TIERS)])
     b_host, x_host, done_h = _run(sde, cfg, step, **kw)
     b_dev, x_dev, done_d = _run(sde, cfg, step, device_resident=True, **kw)
     np.testing.assert_array_equal(x_host, x_dev)
@@ -201,3 +240,150 @@ def test_driver_donates_carry_buffers(server_parts):
     assert before.is_deleted()
     b.run_to_completion()
     assert len(b.finished) == SLOTS
+
+
+# ---------------------------------------------------------------------------
+# admission: seed words in, keys derived on device, fixed uploads per visit
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("seed", WIDE_SEEDS)
+def test_event_program_keys_match_eager_split(server_parts, seed):
+    """The event program derives an admitted request's keys from its seed
+    word: the slot's noise stream and its prior draw equal those of the
+    eager ``jax.random.split(jax.random.PRNGKey(seed))``, for seeds that
+    are negative or wider than 32 bits too."""
+    sde, cfg, step = server_parts
+    b = DiffusionBatcher(sde, step, params=None, sample_shape=(D,),
+                         slots=SLOTS, cfg=cfg, device_resident=True)
+    b.submit(ImageRequest(uid=0, seed=seed))
+    b._process_events(deliver=False)
+    k_prior, k_noise = jax.random.split(jax.random.PRNGKey(seed))
+    np.testing.assert_array_equal(np.asarray(b._carry.key[0]),
+                                  np.asarray(k_noise))
+    np.testing.assert_array_equal(np.asarray(b._carry.x[0]),
+                                  np.asarray(sde.prior_sample(k_prior, (D,))))
+
+
+class _PutCounter:
+    """Counting shim around ``jax.device_put`` — an independent witness
+    of host→device uploads, not the batcher's own
+    ``serve_host_uploads_total``."""
+
+    def __init__(self, monkeypatch):
+        self.calls = 0
+        real = jax.device_put
+
+        def counting(*a, **kw):
+            self.calls += 1
+            return real(*a, **kw)
+
+        monkeypatch.setattr(jax, "device_put", counting)
+
+
+def _seat(b, seeds, monkeypatch):
+    """Submit one request per seed and run the admission-only event visit
+    that seats them all, with implicit host→device transfers refused:
+    an eager op on host values (a key split, a slice, ``jnp.zeros``, a
+    scatter at host indices) would raise. Returns the uploads counted by
+    the batcher and by the shim, and the event program calls."""
+    for s in seeds:
+        b.submit(ImageRequest(uid=1000 + s, seed=s))
+    counters = ("serve_host_uploads_total", "serve_event_updates_total")
+    before = [b.metrics.counter(c).value for c in counters]
+    puts = _PutCounter(monkeypatch)
+    try:
+        with jax.transfer_guard_host_to_device("disallow"):
+            b._process_events(deliver=False)
+    finally:
+        monkeypatch.undo()
+    uploads, events = (int(b.metrics.counter(c).value - v)
+                       for c, v in zip(counters, before))
+    return uploads, puts.calls, events
+
+
+@pytest.mark.parametrize("tiered", [False, True], ids=["untiered", "tiered"])
+def test_event_visit_uploads_are_fixed(server_parts, monkeypatch, tiered):
+    """One event visit uploads one packed admission array (two when
+    tiered: the tolerance rows) and the occupancy mask, whether it seats
+    1 request or 16, and makes no implicit transfer: no per-request
+    eager op is left in the admission."""
+    sde, cfg, step = server_parts
+    b = DiffusionBatcher(sde, step, params=None, sample_shape=(D,),
+                         slots=16, cfg=cfg, sync_horizon=4,
+                         device_resident=True, tolerance_classes=tiered)
+    one = _seat(b, [3], monkeypatch)
+    b.run_to_completion()
+    sixteen = _seat(b, range(100, 116), monkeypatch)
+    want = 3 if tiered else 2
+    assert one == sixteen == (want, want, 1), (one, sixteen)
+    b.run_to_completion()
+    assert len(b.finished) == 17
+
+
+_MESH_PROBE = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
+                               + os.environ.get("XLA_FLAGS", ""))
+    import json
+    import jax
+    from repro.core import AdaptiveConfig, VPSDE
+    from repro.core.analytic import gaussian_noise_pred
+    from repro.launch.mesh import make_data_mesh
+    from repro.launch.sample import make_sample_step
+    from repro.models.dit import DiTConfig
+    from repro.serving.diffusion_server import DiffusionBatcher, ImageRequest
+
+    sde, cfg = VPSDE(), AdaptiveConfig(eps_rel=0.05)
+    net = DiTConfig(image_size=4, patch=4, d_model=8, num_layers=1,
+                    num_heads=1, d_ff=8)
+    step = make_sample_step(net, sde, cfg, forward_fn=gaussian_noise_pred(sde))
+    b = DiffusionBatcher(sde, step, params=None, sample_shape=(32,),
+                         slots=16, cfg=cfg, mesh=make_data_mesh(),
+                         sync_horizon=4, device_resident=True,
+                         tolerance_classes=True)
+    real, puts = jax.device_put, []
+
+    def counting(x, device=None, *a, **kw):
+        puts.append(device == b._carry.done.sharding)
+        return real(x, device, *a, **kw)
+
+    out = {"devices": jax.device_count()}
+    for name, seeds in (("one", [3]), ("sixteen", range(100, 116))):
+        b.run_to_completion()
+        for s in seeds:
+            b.submit(ImageRequest(uid=1000 + s, seed=s, tier="standard"))
+        x, n0, puts[:] = b._carry.x, b.metrics.counter(
+            "serve_host_uploads_total").value, []
+        jax.device_put = counting
+        with jax.transfer_guard_host_to_device("disallow"):
+            b._process_events(deliver=False)
+        jax.device_put = real
+        out[name] = {
+            "uploads": b.metrics.counter("serve_host_uploads_total").value - n0,
+            "puts": len(puts), "slot_sharded": all(puts),
+            "donated": x.is_deleted(),
+        }
+    b.run_to_completion()
+    out["delivered"] = len(b.finished)
+    print(json.dumps(out))
+""")
+
+
+def test_event_visit_uploads_are_fixed_on_a_mesh():
+    """The same fixed uploads on a 4-device CPU mesh (fake host devices in
+    a subprocess), each placed with the carry's slot sharding, and the
+    event program still consumes (donates) the sharded carry."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", _MESH_PROBE], env=env,
+                       capture_output=True, text=True, timeout=300, cwd=root)
+    assert r.returncode == 0, r.stdout + r.stderr
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["devices"] == 4
+    for name in ("one", "sixteen"):
+        v = out[name]
+        assert v["uploads"] == v["puts"] == 3, out
+        assert v["slot_sharded"] and v["donated"], out
+    assert out["delivered"] == 17
