@@ -22,6 +22,8 @@ from chipbench.server import Server
 
 #: how far the benchmark's sinusoidal time embedding is wide
 T_EMB = 256
+#: the device-resident loop's event program, as the trace names it
+EVENT_PROGRAM = "jit_event_update"
 
 
 def shapes(cfg: Dict[str, Any]) -> Dict[str, int]:
@@ -140,6 +142,12 @@ class DitServer(Server):
         self.step_program = cfg["serving"]["step_program"]
         self.flop_per_eval = flop_per_eval(cfg)
         self.sample_shape = sample_shape(cfg)
+
+    @property
+    def programs(self):
+        """The driver and the event program (retire, compact, admit) of
+        ``DiffusionBatcher``'s device-resident loop."""
+        return {"step": self.step_program, "event": EVENT_PROGRAM}
 
     def request(self, uid, spec):
         return self.request_cls(uid=uid, seed=spec["seed"], tier=spec["tier"])

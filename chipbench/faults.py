@@ -1,7 +1,7 @@
 """Faults planted under the timed path (``run.py --fault <name>``), to show
 that the check that decides ``correct`` catches them (``chipbench/tests``):
-``state_unchanged``, ``half_batch``, ``answer_altered``. A run of the
-benchmark plants none."""
+``state_unchanged``, ``half_batch``, ``answer_altered``, and on a mesh
+``exchange_left_out``. A run of the benchmark plants none."""
 
 from __future__ import annotations
 
@@ -36,17 +36,31 @@ def wrap_step(fault):
 def plant_delivery(fault, batcher) -> None:
     """``answer_altered``: every delivered request gets the sample the
     previous delivery carried (the first gets zeros), as a stale buffer
-    would hand it out."""
-    if fault != "answer_altered":
-        return
+    would hand it out. ``exchange_left_out``: the delivery reads every
+    row from the first chip's shard of the slots, so a request seated on
+    another chip gets the row at its position there, as a gather that
+    never crosses chips would hand it out."""
     retire = batcher._retire
-    last = []
+    if fault == "exchange_left_out":
+        per_chip = batcher.slots_per_device
 
-    def altered(rows, *args, **kwargs):
-        rows = np.asarray(rows)
-        prev = last[0] if last else np.zeros_like(rows[0])
-        out = np.concatenate([prev[None], rows[:-1]])
-        last[:] = [rows[-1]]
-        return retire(out, *args, **kwargs)
+        def local(rows, nfe, acc, rej, conv_idx, *args, **kwargs):
+            rows = np.array(rows)
+            for j, i in enumerate(conv_idx):
+                if i >= per_chip:
+                    rows[j] = np.asarray(batcher._carry.x[i % per_chip],
+                                         rows.dtype)
+            return retire(rows, nfe, acc, rej, conv_idx, *args, **kwargs)
 
-    batcher._retire = altered
+        batcher._retire = local
+    elif fault == "answer_altered":
+        last = []
+
+        def altered(rows, *args, **kwargs):
+            rows = np.asarray(rows)
+            prev = last[0] if last else np.zeros_like(rows[0])
+            out = np.concatenate([prev[None], rows[:-1]])
+            last[:] = [rows[-1]]
+            return retire(out, *args, **kwargs)
+
+        batcher._retire = altered

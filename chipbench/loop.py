@@ -31,6 +31,10 @@ class Record:
     delivered: Optional[float] = None
     nfe: int = 0
     result: Any = None
+    #: the program's request object, once delivered
+    request: Any = None
+    #: the slot that seated it (each chip holds a contiguous block)
+    slot: Optional[int] = None
 
     @property
     def latency(self) -> Optional[float]:
@@ -43,7 +47,7 @@ class Outcome:
     t0: float
     t1: float
     delivered_in_window: List[Record]
-    counters: Dict[str, int]  # deltas over the window
+    counters: Dict[str, int]  # deltas over the window, as ``counters`` keys them
     lateness: List[float]
     #: due before the window closed and never delivered
     undelivered: int
@@ -51,9 +55,23 @@ class Outcome:
     t_end: float
 
 
+#: the serve loop's four counters under the benchmark's own short names
+LEGACY = ("iterations", "useful_nfe", "resident_nfe", "transfers")
+
+
 def counters(b) -> Dict[str, int]:
+    """The batcher's counters now: the four of ``LEGACY``, and every
+    counter series of its metrics registry under the registry's name
+    (``name`` or, labelled, ``name{k="v",...}``)."""
     return {"iterations": b.total_iterations, "useful_nfe": b.useful_nfe,
-            "resident_nfe": b.resident_nfe, "transfers": b.host_transfers}
+            "resident_nfe": b.resident_nfe, "transfers": b.host_transfers,
+            **b.metrics.to_json()["counters"]}
+
+
+def deltas(start: Dict[str, int], end: Dict[str, int]) -> Dict[str, int]:
+    """``end - start`` for every key of ``end`` (a labelled series that
+    first appeared in between started at 0)."""
+    return {k: v - start.get(k, 0) for k, v in end.items()}
 
 
 def warm_up(server, limit_s: float) -> bool:
@@ -88,13 +106,13 @@ def warm_up(server, limit_s: float) -> bool:
 class TraceSlice:
     """Profiles ``length_s`` seconds that end with the window, starting
     and stopping at turn boundaries (the device is idle there), and
-    counts the solver iterations and useful NFE run inside."""
+    counts the solver iterations and useful NFE run inside, and the
+    deltas of the batcher's ``counters``."""
 
-    def __init__(self, server, log_dir: str, length_s: float, tracer,
+    def __init__(self, server, log_dir: str, length_s: float,
                  chrome: bool = False):
         self.server, self.log_dir, self.length_s = server, log_dir, length_s
         self.chrome = chrome
-        self.tracer = tracer
         self.state = "waiting"
         self.nfe0: Dict[int, int] = {}
         self.delivered: Dict[int, int] = {}
@@ -126,9 +144,9 @@ class TraceSlice:
                                      create_perfetto_trace=self.chrome)
             self.ann = jax.profiler.TraceAnnotation("bench/trace")
             self.ann.__enter__()
-            self.mark = self.tracer.clock()
             self.it0 = self._iterations()
             self.nfe0 = self._inflight_nfe()
+            self.c0 = counters(self.server.batcher)
             self.state = "on"
         elif self.state == "on" and now >= t1:
             self.stop()
@@ -143,6 +161,7 @@ class TraceSlice:
         if self.state != "on":
             return
         self.iterations = self._iterations() - self.it0
+        self.counters = deltas(self.c0, counters(self.server.batcher))
         nfe1 = self._inflight_nfe()
         self.useful_nfe = (
             sum(n - self.nfe0.get(u, 0) for u, n in self.delivered.items())
@@ -157,6 +176,17 @@ def serve(server, traffic, seconds: float, *, max_wait_s: float,
     """Serve ``traffic`` for a window of ``seconds``; request ``i`` of the
     mix gets the uid ``uid_base + i``."""
     b = server.batcher
+    seats: Dict[int, int] = {}
+    admit = b._admit_from_queue
+
+    def seat():
+        # note the slot of each request the batcher seats
+        pos, reqs = admit()
+        for i, req in zip(pos, reqs):
+            seats[req.uid] = i
+        return pos, reqs
+
+    b._admit_from_queue = seat
     if trace is not None:
         import jax
 
@@ -227,6 +257,7 @@ def serve(server, traffic, seconds: float, *, max_wait_s: float,
                     if rec is None:  # left over from the warm-up
                         continue
                     rec.delivered, rec.nfe, rec.result = done_at, req.nfe, req.result
+                    rec.request, rec.slot = req, seats.get(req.uid)
                     delivered_total += 1
                     if trace is not None:
                         trace.on_delivered(req.uid, req.nfe)
@@ -240,12 +271,13 @@ def serve(server, traffic, seconds: float, *, max_wait_s: float,
                 time.sleep(max(0.0, t_start + traffic.due(nxt) - clock()))
     if trace is not None:
         trace.stop()
+    b._admit_from_queue = admit
     population = [r for r in recs.values() if t0 <= r.due < t1]
     in_window = [r for r in recs.values()
                  if r.delivered is not None and t0 <= r.delivered < t1]
     return Outcome(
         population=population, t0=t0, t1=t1, delivered_in_window=in_window,
-        counters={k: c_end[k] - c_start[k] for k in c_start},
+        counters=deltas(c_start, c_end),
         lateness=lateness,
         undelivered=sum(1 for r in recs.values()
                         if r.due < t1 and r.delivered is None),

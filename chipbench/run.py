@@ -178,19 +178,21 @@ def end_to_end(outcome, setup_s, seconds):
     }
 
 
-def check(server, outcome, seed, limits, log):
-    """The numbers that decide ``correct``, each beside the cell's limit."""
+def check(server, outcome, seed, limits, log, per_chip):
+    """The numbers that decide ``correct``, each beside the cell's limit;
+    ``per_chip``: how many slots each chip holds."""
     from chipbench.server import Check, Delivered, relative_gap
     from chipbench.traffic import sample_indices
 
-    done = [Delivered(r.spec, r.result, r.nfe) for r in outcome.population
-            if r.delivered is not None]
+    done = [Delivered(r.spec, r.result, r.nfe, r.slot // per_chip)
+            for r in outcome.population if r.delivered is not None]
     checks = [Check("undelivered", float(outcome.undelivered),
                     limits["undelivered"])]
     if done:
         longest = max(range(len(done)), key=lambda i: done[i].nfe)
+        # drawn from every chip's slots alike, so a fault on one chip shows
         picks = sample_indices(seed, len(done), limits["sample_requests"],
-                               longest)
+                               longest, groups=[d.chip for d in done])
         t = time.perf_counter()
         ref = server.reference_solve([done[i].spec for i in picks])
         gaps = [relative_gap(done[i].result, ref[j])
@@ -249,6 +251,8 @@ def main(argv=None, *, root: pathlib.Path = ROOT,
     clock = CompileClock()
     tracer = None
     if args.trace:
+        # the program's stage tracer: its spans are profiler annotations
+        # that the trace keeps (``ctx.spans``)
         from repro.observability.tracing import StageTracer
 
         tracer = StageTracer(clock=time.perf_counter)
@@ -276,7 +280,7 @@ def main(argv=None, *, root: pathlib.Path = ROOT,
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") \
         if args.trace else None
     sl = (loop.TraceSlice(server, trace_dir, min(TRACE_SECONDS, args.seconds),
-                          tracer, chrome=bool(args.keep_trace))
+                          chrome=bool(args.keep_trace))
           if args.trace else None)
     with watch_compiles() as late:
         outcome = loop.serve(server, traffic, args.seconds,
@@ -302,10 +306,10 @@ def main(argv=None, *, root: pathlib.Path = ROOT,
     metrics = {}
     breakdown = None
     if args.trace:
-        from chipbench import trace as tr
-
-        ctx = layer_context(server, outcome, sl, trace_dir, tracer, peaks,
-                            compile_s)
+        files = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
+        ctx = layer_context(str(files[-1]), server=server, family=family,
+                            cfg=cfg, mix=mix, outcome=outcome, sl=sl,
+                            peaks=peaks, compile_s=compile_s, device=device)
         if args.keep_trace:
             keep = pathlib.Path(args.keep_trace)
             keep.mkdir(parents=True, exist_ok=True)
@@ -331,9 +335,10 @@ def main(argv=None, *, root: pathlib.Path = ROOT,
                                   "unit": m["unit"]}
 
     # the program's state goes before the reference runs on the chip
+    per_chip = server.batcher.slots_per_device
     server.release()
     gc.collect()
-    checks = check(server, outcome, args.seed, mix["check"], log)
+    checks = check(server, outcome, args.seed, mix["check"], log, per_chip)
     for c in checks:
         log(f"check {c.name} = {c.value!r} (limit {c.limit!r})"
             f" {'ok' if c.ok else 'FAILED'}")
@@ -348,32 +353,55 @@ def main(argv=None, *, root: pathlib.Path = ROOT,
 
 
 class Context:
-    """What a per-layer metric reader gets."""
+    """What a per-layer metric reader gets: what the run observed.
+
+    ``trace``: ``trace.reduce``'s numbers over the traced slice, with
+    ``program_s`` and ``program_runs`` for every program of
+    ``programs`` (role -> jit name; ``step_program`` is the step's).
+    ``spans``: the host spans of the slice, ``(start_ns, end_ns, name)``
+    on the trace's clock, cut to the slice. ``slice`` and ``window``:
+    iterations and useful NFE of the slice, the window's delivered
+    ``Record``s and the serve loop's four counters (``loop.LEGACY``),
+    and under ``counters`` the deltas of every counter series of the
+    batcher's metrics registry over each. ``requests``: the program's
+    requests delivered in the window. ``cfg``, ``mix``, ``family``: the
+    configuration, the traffic mix and the family module (FLOPs and
+    bytes from shapes). ``peaks``: the chip's ``ChipPeaks``
+    (``peak_flops`` is its bf16 FLOP/s). ``device``: the run's device
+    record (``memory_peak_bytes``). ``compile_s``: set-up's backend
+    compile seconds."""
 
 
-def layer_context(server, outcome, sl, trace_dir, tracer, peaks, compile_s):
-    from chipbench import trace as tr
+def layer_context(trace_file, *, server, family, cfg, mix, outcome, sl,
+                  peaks, compile_s, device):
+    from chipbench import loop, trace as tr
 
-    files = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
-    chips, host = tr.load(str(files[-1]))
+    chips, host = tr.load(trace_file)
     window = [s for s in host if s[2] == "bench/trace"]
     if not window:
         raise RuntimeError("the trace holds no bench/trace span")
     t0, t1 = window[0][0], window[0][1]
-    # the serve loop's own stage spans, moved onto the trace's clock
-    host = host + [(t0 + int((s["start"] - sl.mark) * 1e9),
-                    t0 + int((s["end"] - sl.mark) * 1e9), s["name"])
-                   for s in tracer.spans
-                   if s["name"] != "serve/solve" and s["start"] >= sl.mark]
     ctx = Context()
+    ctx.programs = dict(server.programs)
     ctx.trace = tr.reduce(chips, host, t0, t1,
-                          programs=[server.step_program])
-    ctx.slice = {"iterations": sl.iterations, "useful_nfe": sl.useful_nfe}
+                          programs=list(ctx.programs.values()))
+    ctx.spans = tr.clip(host, t0, t1)
+
+    def registry(counters):
+        return {k: v for k, v in counters.items() if k not in loop.LEGACY}
+
     ctx.window = {"delivered": outcome.delivered_in_window,
-                  **outcome.counters}
+                  **{k: outcome.counters[k] for k in loop.LEGACY},
+                  "counters": registry(outcome.counters)}
+    ctx.slice = {"iterations": sl.iterations, "useful_nfe": sl.useful_nfe,
+                 "counters": registry(sl.counters)}
+    ctx.requests = [r.request for r in outcome.delivered_in_window]
     ctx.step_program = server.step_program
     ctx.flop_per_eval = server.flop_per_eval
+    ctx.cfg, ctx.mix, ctx.family = cfg, mix, family
+    ctx.peaks = peaks
     ctx.peak_flops = peaks.flops_bf16
+    ctx.device = device
     ctx.compile_s = compile_s
     return ctx
 

@@ -3,10 +3,10 @@ decides ``correct``.
 
 A family module (``families/<family>.py``) builds a :class:`Server` around
 the program's ``DiffusionBatcher``: it turns a traffic spec into the
-program's request, names the step program for the trace, counts the FLOPs
-of one score evaluation, and gives the plain reference of its score
-network. The check here compares what the timed path delivered with the
-plain reference's solve of the same requests.
+program's request, names the programs that the trace times, counts the
+FLOPs of one score evaluation, and gives the plain reference of its
+score network. The check here compares what the timed path
+delivered with the plain reference's solve of the same requests.
 """
 
 from __future__ import annotations
@@ -27,6 +27,8 @@ class Delivered:
     spec: Dict[str, Any]
     result: np.ndarray
     nfe: int
+    #: the chip whose slots served it
+    chip: int = 0
 
 
 @dataclasses.dataclass
@@ -50,6 +52,11 @@ class Server:
 
     def __init__(self, cfg: Dict[str, Any]):
         self.cfg = cfg
+
+    @property
+    def programs(self) -> Dict[str, str]:
+        """The programs the trace times, by role: role -> jit name."""
+        return {"step": self.step_program}
 
     def request(self, uid: int, spec: Dict[str, Any]):
         raise NotImplementedError

@@ -131,6 +131,20 @@ def test_check_sample_holds_the_longest():
     assert sample_indices(3, 5, 12, must=1) == [0, 1, 2, 3, 4]
 
 
+def test_check_sample_draws_from_every_chip():
+    """On a mesh the sample takes as many requests from each chip's slots
+    as the others (all of a chip's, where it served fewer); with one chip
+    it is the draw of one group."""
+    chip = [0] * 40 + [1] * 40 + [2] * 2 + [3] * 18
+    for seed in range(5):
+        picks = sample_indices(seed, 100, 12, must=57, groups=chip)
+        assert 57 in picks and len(set(picks)) == 12
+        per = [sum(chip[i] == c for i in picks) for c in range(4)]
+        assert per[2] == 2 and sorted(per) == [2, 3, 3, 4]
+        assert sample_indices(seed, 100, 12, must=57, groups=[0] * 100) \
+            == sample_indices(seed, 100, 12, must=57)
+
+
 def test_percentiles_and_spread_follow_the_statistics_module():
     import statistics
 

@@ -18,6 +18,7 @@ Times are in seconds. Numbers over several chips are means per chip.
 from __future__ import annotations
 
 import collections
+import re
 from typing import Dict, Iterable, List, Sequence, Tuple
 
 Interval = Tuple[int, int]  # (start_ns, end_ns)
@@ -102,8 +103,9 @@ def _events(line) -> List[Tuple[int, int, str]]:
 
 def load(path: str):
     """(chips, host spans) of an ``.xplane.pb`` file. Host spans are the
-    events of the host plane named ``<stage>/...`` with a stage of
-    ``STAGE_PREFIXES``."""
+    events of the host plane named ``<stage>/...``: the benchmark's own
+    (``bench/``), the serve loop's (``serve/``, ``plan/``) and any other
+    stage a program names its spans by."""
     import jax
 
     pdata = jax.profiler.ProfileData.from_file(path)
@@ -118,14 +120,12 @@ def load(path: str):
             chips[int(plane.name.rsplit(":", 1)[1])] = Chip(ops, mods)
         elif plane.name == "/host:CPU":
             for ln in plane.lines:
-                host.extend(ev for ev in _events(ln) if "/" in ev[2]
-                            and ev[2].split("/", 1)[0] in STAGE_PREFIXES)
+                host.extend(ev for ev in _events(ln) if STAGE.match(ev[2]))
     return chips, host
 
 
-#: host spans that label idle gaps: the benchmark's own generator phases
-#: and the serve loop's stages
-STAGE_PREFIXES = ("bench", "serve", "plan")
+#: a host span's name: a lower-case stage, ``/``, what it covers
+STAGE = re.compile(r"[a-z][a-z0-9_]*/.")
 
 
 def labels(times: Sequence[int],
@@ -146,6 +146,13 @@ def labels(times: Sequence[int],
             stack.pop()
         out.append(stack[-1][2] if stack else "(no span)")
     return out
+
+
+def clip(spans: Iterable[Tuple[int, int, str]], t0: int,
+         t1: int) -> List[Tuple[int, int, str]]:
+    """The spans that overlap ``[t0, t1]``, cut to it, in start order."""
+    return sorted((max(s, t0), min(e, t1), n) for s, e, n in spans
+                  if s < t1 and e > t0)
 
 
 def reduce(chips: Dict[int, Chip], host: Sequence[Tuple[int, int, str]],

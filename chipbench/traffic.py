@@ -25,7 +25,8 @@ past the window.
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+import itertools
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -151,8 +152,19 @@ class Traffic:
         return k * self.clients + client
 
 
-def sample_indices(seed: int, n: int, k: int, must: int) -> List[int]:
-    """``k`` of ``range(n)`` drawn from the seed, always holding ``must``."""
+def sample_indices(seed: int, n: int, k: int, must: int,
+                   groups: Optional[Sequence[int]] = None) -> List[int]:
+    """``k`` of ``range(n)`` drawn from the seed, always holding ``must``.
+    With ``groups`` (one per index: the chip that served it) the draw is
+    taken from each group in turn, so every group that can gives as many
+    as the others."""
     rest = [i for i in range(n) if i != must]
-    pick = _rng(seed, _SAMPLE).permutation(len(rest))[: max(k - 1, 0)]
-    return sorted([must] + [rest[j] for j in pick])
+    order = [must] + [rest[j] for j in
+                      _rng(seed, _SAMPLE).permutation(len(rest))]
+    if groups is not None:
+        by: Dict[int, List[int]] = {}
+        for i in order:
+            by.setdefault(groups[i], []).append(i)
+        order = [i for turn in itertools.zip_longest(*by.values())
+                 for i in turn if i is not None]
+    return sorted(order[: max(k, 1)])
